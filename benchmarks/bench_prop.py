@@ -1354,6 +1354,9 @@ if __name__ == "__main__":
         "--smoke, asserted against a throwaway copy instead)",
     )
     ns = parser.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     jax.config.update("jax_enable_x64", True)  # match benchmarks.run
     rows = list(smoke() if ns.smoke else run())
     if ns.telemetry:

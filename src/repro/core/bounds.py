@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from .types import INF, int_round_slack
+from .types import INF, any_true, clamp_to_sentinel, int_round_slack
 
 
 def bound_candidates(a, lhs_row, rhs_row, min_res, max_res, inf: float = INF):
@@ -136,9 +136,9 @@ def apply_updates(
     take_u = improved_ub(best_ucand, ub, eps)
     if outward:
         best_lcand, best_ucand = widen_outward(best_lcand, best_ucand, outward)
-    new_lb = jnp.where(take_l, jnp.clip(best_lcand, -inf, inf), lb)
-    new_ub = jnp.where(take_u, jnp.clip(best_ucand, inf * -1, inf), ub)
-    changed = jnp.any(take_l) | jnp.any(take_u)
+    new_lb = jnp.where(take_l, clamp_to_sentinel(best_lcand, inf), lb)
+    new_ub = jnp.where(take_u, clamp_to_sentinel(best_ucand, inf), ub)
+    changed = any_true(take_l) | any_true(take_u)
     return new_lb, new_ub, changed
 
 
@@ -157,8 +157,8 @@ def apply_updates_batch(
     take_u = improved_ub(best_ucand, ub, eps)
     if outward:
         best_lcand, best_ucand = widen_outward(best_lcand, best_ucand, outward)
-    new_lb = jnp.where(take_l, jnp.clip(best_lcand, -inf, inf), lb)
-    new_ub = jnp.where(take_u, jnp.clip(best_ucand, -inf, inf), ub)
+    new_lb = jnp.where(take_l, clamp_to_sentinel(best_lcand, inf), lb)
+    new_ub = jnp.where(take_u, clamp_to_sentinel(best_ucand, inf), ub)
     changed = jnp.any(take_l, axis=-1) | jnp.any(take_u, axis=-1)
     return new_lb, new_ub, changed
 

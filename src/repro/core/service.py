@@ -68,7 +68,7 @@ from ..obs.telemetry import TelemetryPlane, TelemetrySnapshot, reset_rows
 from ..obs.trace import NULL_TRACER
 from .propagator import batched_step_rounds, donate_kwargs
 from .sparse import LANE, Problem, SlotPayload, col_pad, evict_slot, pack_into_slot
-from .types import DEFAULT_CONFIG, PropagationResult, PropagatorConfig
+from .types import DEFAULT_CONFIG, PropagationResult, PropagatorConfig, resolve_dtype
 
 # Resident bucket state layout (flat tuple, so the jitted step/admit engines
 # can donate individual buffers):
@@ -352,6 +352,8 @@ class _BucketEngine:
         self.np_dtype = np.dtype(dtype)
         self.dev_dtype = jnp.asarray(np.zeros(0, self.np_dtype)).dtype
         self.eps = cfg.eps_for(self.dev_dtype)
+        if use_pallas:
+            interpret = kern.resolve_interpret(interpret, self.dev_dtype)
         self._lock = threading.RLock()
         self.warmed = False
 
@@ -594,10 +596,11 @@ class PropagationService:
     ``patience`` consecutive rounds is deactivated inside the device step
     and retired at the next step boundary with ``converged=False`` and the
     last measure in ``PropagationResult.progress`` -- freeing the slot for
-    the backlog instead of grinding out epsilon-level tail rounds.  A
-    whole-service fp32 tier is ``dtype=np.float32`` (the engines apply the
-    outward-rounded merge automatically); per-slot tier promotion is not a
-    service feature -- resubmit promoted instances to an fp64 service.
+    the backlog instead of grinding out epsilon-level tail rounds.
+    ``dtype=None`` serves in float64, or in float32 on a TPU; a float32
+    service applies the outward-rounded merge automatically.  Per-slot tier
+    promotion is not a service feature -- resubmit promoted instances to
+    an fp64 service.
 
     Observability: ``telemetry`` (a ring capacity) arms per-slot device
     telemetry -- retired tickets' results carry an
@@ -614,7 +617,7 @@ class PropagationService:
         self,
         specs: Sequence[BucketSpec],
         cfg: PropagatorConfig = DEFAULT_CONFIG,
-        dtype=np.float64,
+        dtype=None,
         rounds_per_step: int = 8,
         use_pallas: bool | None = None,
         interpret: bool | None = None,
@@ -629,7 +632,7 @@ class PropagationService:
         if use_pallas is None:
             use_pallas = not kern._on_cpu()
         self._cfg = cfg
-        self._dtype = np.dtype(dtype)
+        dtype = self._dtype = resolve_dtype(dtype, np.float64)
         self._stop_progress = stop_progress
         self._telemetry = int(telemetry or 0)
         self._tracer = NULL_TRACER if tracer is None else tracer

@@ -19,34 +19,18 @@ the GPU" (§3.7).
 from __future__ import annotations
 
 import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:  # jax >= 0.6 exports shard_map at the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-compatible ``shard_map``: newer jax spells the "skip the
-    varying-manual-axes check" flag ``check_vma``, older jax ``check_rep``."""
-    try:
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    except TypeError:
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-        )
 
 from . import activities as act
 from . import bounds as bnd
 from .propagator import donate_kwargs, initial_bounds
 from .sparse import Problem
-from .types import DEFAULT_CONFIG, INF, PropagationResult, PropagatorConfig
+from .types import DEFAULT_CONFIG, INF, PropagationResult, PropagatorConfig, resolve_dtype
 
 
 def partition_nnz(p: Problem, num_shards: int):
@@ -67,7 +51,8 @@ def partition_nnz(p: Problem, num_shards: int):
 
 
 def _sharded_round(
-    row_id, col, val, lhs, rhs, is_int, lb, ub, *, m, n, eps, int_eps, inf, axes
+    row_id, col, val, lhs, rhs, is_int, lb, ub, *, m, n, eps, int_eps, inf, axes,
+    outward=0.0,
 ):
     """One round on the local nnz shard + collectives. Runs under shard_map."""
     lb_col = lb[col]
@@ -96,7 +81,7 @@ def _sharded_round(
     best_l = jax.lax.pmax(jax.ops.segment_max(lcand, col, num_segments=n), axes)
     best_u = jax.lax.pmin(jax.ops.segment_min(ucand, col, num_segments=n), axes)
 
-    return bnd.apply_updates(lb, ub, best_l, best_u, eps, inf)
+    return bnd.apply_updates(lb, ub, best_l, best_u, eps, inf, outward)
 
 
 def propagate_sharded(
@@ -111,10 +96,12 @@ def propagate_sharded(
 
     ``lb0``/``ub0`` warm-start the fixed point from caller-supplied bounds
     (default: the problem's root bounds); the replicated bound vectors are
-    the only per-call state, so one partitioned matrix serves any node."""
+    the only per-call state, so one partitioned matrix serves any node.
+    ``dtype=None`` is the instance's own dtype, float32 on a TPU (merged
+    with outward rounding)."""
     axes = tuple(mesh.axis_names)
     num_shards = int(np.prod(mesh.devices.shape))
-    dtype = dtype or p.csr.val.dtype
+    dtype = resolve_dtype(dtype, p.csr.val.dtype)
     eps = cfg.eps_for(dtype)
 
     row_id, col, val = partition_nnz(p, num_shards)
@@ -138,6 +125,7 @@ def propagate_sharded(
         int_eps=cfg.int_eps,
         inf=cfg.inf,
         axes=axes,
+        outward=cfg.outward_for(dtype),
     )
 
     def shard_body(row_id, col, val, lhs, rhs, is_int, lb0, ub0):
@@ -222,7 +210,8 @@ def partition_rows(p: Problem, num_shards: int):
 
 
 def _row_sharded_round(
-    lrow, col, val, lhs, rhs, is_int, lb, ub, *, rows, n, eps, int_eps, inf, axes
+    lrow, col, val, lhs, rhs, is_int, lb, ub, *, rows, n, eps, int_eps, inf, axes,
+    outward=0.0,
 ):
     """One round with rows complete on-shard: NO activity collective."""
     lb_col = lb[col]
@@ -250,7 +239,7 @@ def _row_sharded_round(
     # The only collective of the round: the atomic-free bound combine.
     best_l = jax.lax.pmax(jax.ops.segment_max(lcand, col, num_segments=n), axes)
     best_u = jax.lax.pmin(jax.ops.segment_min(ucand, col, num_segments=n), axes)
-    return bnd.apply_updates(lb, ub, best_l, best_u, eps, inf)
+    return bnd.apply_updates(lb, ub, best_l, best_u, eps, inf, outward)
 
 
 def propagate_sharded_rows(
@@ -266,7 +255,7 @@ def propagate_sharded_rows(
     ``lb0``/``ub0`` warm-start the fixed point from caller-supplied bounds."""
     axes = tuple(mesh.axis_names)
     num_shards = int(np.prod(mesh.devices.shape))
-    dtype = dtype or p.csr.val.dtype
+    dtype = resolve_dtype(dtype, p.csr.val.dtype)
     eps = cfg.eps_for(dtype)
 
     val, col, lrow, lhs, rhs, rows = partition_rows(p, num_shards)
@@ -279,6 +268,7 @@ def propagate_sharded_rows(
         int_eps=cfg.int_eps,
         inf=cfg.inf,
         axes=axes,
+        outward=cfg.outward_for(dtype),
     )
 
     def shard_body(lrow, col, val, lhs, rhs, is_int, lb0, ub0):
@@ -370,7 +360,8 @@ def _build_batch_shard_runner(problems, mesh, cfg, tile_rows, tile_width, dtype)
     b_max = max(prep.size for _, _, prep in preps)
     m_max = max(prep.m_total for _, _, prep in preps)
     fits = all(prep.fits_one_chunk for _, _, prep in preps)
-    eps = cfg.eps_for(preps[0][2].d.val.dtype)
+    dt = preps[0][2].d.val.dtype
+    eps = cfg.eps_for(dt)
 
     def pad_to(x, size, axis=0, fill=0):
         pad = size - x.shape[axis]
@@ -403,7 +394,7 @@ def _build_batch_shard_runner(problems, mesh, cfg, tile_rows, tile_width, dtype)
 
     round_kw = dict(
         m_total=m_max, n_pad=n_pad, fits_one_chunk=fits,
-        eps=eps, int_eps=cfg.int_eps, inf=cfg.inf,
+        eps=eps, int_eps=cfg.int_eps, inf=cfg.inf, outward=cfg.outward_for(dt),
     )
 
     def shard_body(val, col_g, crow, ii_g, lhs_g, rhs_g, lb0, ub0, active0, col_valid):
@@ -445,6 +436,41 @@ def _build_batch_shard_runner(problems, mesh, cfg, tile_rows, tile_width, dtype)
     }
 
 
+def run_batch_sharded(
+    problems,
+    mesh: Mesh,
+    cfg: PropagatorConfig = DEFAULT_CONFIG,
+    tile_rows: int = 8,
+    tile_width: int = 128,
+    dtype=None,
+):
+    """Run the batch-axis sharded fixed point and return ``(layout,
+    outputs)``: the per-shard ``(members, bucket, prep)`` layout and the
+    stacked ``(shard, instance, ...)`` device outputs ``(lb, ub, rounds,
+    converged, infeasible)``, each sharded over ``mesh``.  See
+    :func:`propagate_batch_sharded` for the per-instance front end."""
+    from .propagator import owned_copy
+
+    dt = np.dtype(dtype).str if dtype is not None else None
+    key = (tuple(id(p) for p in problems), mesh, cfg, tile_rows, tile_width, dt)
+    hit = _batch_shard_cache.get(key)
+    if hit is not None and all(a is b for a, b in zip(hit[0], problems)):
+        built = hit[1]
+    else:
+        built = _build_batch_shard_runner(
+            problems, mesh, cfg, tile_rows, tile_width, dtype
+        )
+        _batch_shard_cache[key] = (tuple(problems), built)
+        while len(_batch_shard_cache) > _BATCH_SHARD_CACHE_CAPACITY:
+            _batch_shard_cache.pop(next(iter(_batch_shard_cache)))
+
+    args = list(built["args"])
+    # Private copies of the cached initial bounds: they are donated into the
+    # on-device loop and must not invalidate the cached runner's buffers.
+    args[6], args[7] = owned_copy(args[6]), owned_copy(args[7])
+    return built["preps"], built["run"](*args)
+
+
 def propagate_batch_sharded(
     problems,
     mesh: Mesh,
@@ -468,34 +494,22 @@ def propagate_batch_sharded(
     list, so a serving loop re-propagating the same instances pays
     partitioning and compilation once.
 
-    Returns one ``PropagationResult`` per instance, input order.
+    Returns one ``PropagationResult`` per instance, input order, with host
+    (numpy) arrays.
     """
-    from .propagator import owned_copy
-
     problems = list(problems)
     if not problems:
         return []
-    dt = np.dtype(dtype).str if dtype is not None else None
-    key = (tuple(id(p) for p in problems), mesh, cfg, tile_rows, tile_width, dt)
-    hit = _batch_shard_cache.get(key)
-    if hit is not None and all(a is b for a, b in zip(hit[0], problems)):
-        built = hit[1]
-    else:
-        built = _build_batch_shard_runner(
-            problems, mesh, cfg, tile_rows, tile_width, dtype
-        )
-        _batch_shard_cache[key] = (tuple(problems), built)
-        while len(_batch_shard_cache) > _BATCH_SHARD_CACHE_CAPACITY:
-            _batch_shard_cache.pop(next(iter(_batch_shard_cache)))
-
-    args = list(built["args"])
-    # Private copies of the cached initial bounds: they are donated into the
-    # on-device loop and must not invalidate the cached runner's buffers.
-    args[6], args[7] = owned_copy(args[6]), owned_copy(args[7])
-    lb, ub, rounds, converged, infeasible = built["run"](*args)
+    layout, outputs = run_batch_sharded(
+        problems, mesh, cfg, tile_rows, tile_width, dtype
+    )
+    # One host transfer of the stacked (shard, instance, ...) outputs, then
+    # per-instance slices in numpy: eager indexing of a sharded array would
+    # need an explicit out_sharding per slice.
+    lb, ub, rounds, converged, infeasible = jax.device_get(outputs)
 
     out = [None] * len(problems)
-    for s, (members, bucket, prep) in enumerate(built["preps"]):
+    for s, (members, bucket, prep) in enumerate(layout):
         if not members:
             continue  # idle shard carries an inactive dummy instance
         for i, (sub_idx, p) in enumerate(zip(bucket.indices, bucket.problems)):

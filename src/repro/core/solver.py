@@ -189,6 +189,7 @@ def _make_level_step(prep, cfg, rule, use_pallas, interpret, prune_gap,
 
     round_fn = node_round_fn_for(prep, cfg, use_pallas, interpret)
     pallas_objective = bool(use_pallas) and n_pad <= 2**16
+    widened = bool(cfg.outward_for(prep.d.val.dtype))
 
     def step(c: SearchCarry, c_pad) -> SearchCarry:
         cap = c.status.shape[0]
@@ -199,6 +200,12 @@ def _make_level_step(prep, cfg, rule, use_pallas, interpret, prune_gap,
         lb, ub, _, _ = batched_fixed_point(
             round_fn, c.lb, c.ub, cfg.max_rounds, active0=open_m
         )
+        if widened:
+            # A float32 merge widens each rounded integer bound outward
+            # off the integer; no integer point lies in between, so
+            # ceil/floor restore it and leaves keep exact objectives.
+            lb = jnp.where(ii, jnp.ceil(lb), lb)
+            ub = jnp.where(ii, jnp.floor(ub), ub)
 
         # (2) Objective bound + leaf / infeasibility predicates.
         if pallas_objective:

@@ -14,11 +14,25 @@ from __future__ import annotations
 import dataclasses
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 # SCIP-style infinity sentinel.  Values beyond this magnitude are "infinite".
 INF = 1e20
+
+
+def resolve_dtype(dtype, fallback) -> np.dtype:
+    """The engines' value dtype: ``dtype`` when given; otherwise float32 on
+    a TPU -- Mosaic kernels take no 64-bit operand, and the float32 engines
+    merge with outward rounding (``PropagatorConfig.outward_eps_f32``) so
+    they are never tighter than float64 -- and ``fallback`` (the instance's
+    own dtype) elsewhere."""
+    if dtype is not None:
+        return np.dtype(dtype)
+    if jax.default_backend() == "tpu":
+        return np.dtype(np.float32)
+    return np.dtype(fallback)
 
 
 def _is_low_precision(dtype) -> bool:
@@ -168,8 +182,26 @@ def is_inf(v, inf: float = INF):
 
 
 def clamp_to_sentinel(v, inf: float = INF):
-    """Clamp values into the representable range [-INF, INF]."""
-    return jnp.clip(v, -inf, inf)
+    """Clamp values into the representable range [-INF, INF] (``jnp.clip``
+    semantics, spelled so a TPU kernel can lower it under x64: see
+    :func:`scalar_like`)."""
+    return jnp.minimum(jnp.maximum(v, -inf), inf)
+
+
+def any_true(x, axis=None):
+    """``jnp.any`` spelled as a float32 max: JAX lowers a boolean reduction
+    inside a TPU kernel through a float of the default width, float64 under
+    x64, which the kernel cannot hold."""
+    one, zero = np.float32(1.0), np.float32(0.0)
+    return jnp.max(jnp.where(x, one, zero), axis=axis) > zero
+
+
+def scalar_like(v: float, x):
+    """``v`` as a 0-d array of ``x``'s dtype.  ``jnp.where`` and
+    ``jnp.clip`` take a bare Python float as a float64 operand when x64 is
+    on, which a TPU kernel cannot lower; a typed scalar holds the same
+    value (``v`` rounded to the dtype once, as the implicit cast did)."""
+    return np.asarray(v, dtype=x.dtype)
 
 
 def np_is_inf(v: np.ndarray, inf: float = INF) -> np.ndarray:

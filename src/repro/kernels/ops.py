@@ -88,6 +88,7 @@ from ..core.types import (
     PropagatorConfig,
     TierPolicy,
     _is_low_precision,
+    resolve_dtype,
 )
 from ..obs import telemetry as obs
 from . import prop_round as kern
@@ -122,7 +123,7 @@ def device_block_ell(p: Problem, tile_rows: int = 8, tile_width: int = 128, dtyp
     padding row, bounds and integrality marks as ``(n,)`` device arrays.
     Prefer :func:`prepare_block_ell`, which caches this and hoists the
     round-constant gathers."""
-    dtype = dtype or p.csr.val.dtype
+    dtype = resolve_dtype(dtype, p.csr.val.dtype)
     b = csr_to_block_ell(p.csr, tile_rows=tile_rows, tile_width=tile_width)
     pad1 = lambda x: np.concatenate([x, np.zeros(1, dtype=x.dtype)])
     return DeviceBlockEll(
@@ -697,8 +698,10 @@ def prepare_block_ell(
     structure arrays so ``id()`` keys cannot be recycled while an entry is
     live; a hit from a problem whose bounds differ from the cached defaults
     returns a cheap bounds-swapped view sharing every device tile.
+    ``dtype=None`` is the instance's own dtype, float32 on a TPU
+    (:func:`~repro.core.types.resolve_dtype`).
     """
-    dt = np.dtype(dtype) if dtype is not None else np.dtype(p.csr.val.dtype)
+    dt = resolve_dtype(dtype, p.csr.val.dtype)
     anchors = _structure_anchors(p)
     key = tuple(id(a) for a in anchors) + (tile_rows, tile_width, dt.str)
     hit = _prep_cache.get(key, anchors)
@@ -934,6 +937,8 @@ def _prepared_round(
     """One round over hoisted constants.  (lb, ub) live in the column-padded
     ``(n_pad,)`` domain end to end; only the bound gathers run in XLA."""
     d = prep.d
+    if use_pallas:
+        interpret = kern.resolve_interpret(interpret, lb.dtype)
 
     if scatter == "partitioned":
         # Column-slab partitioned round (VMEM-exceeding n_pad): chunk-copy
@@ -1262,17 +1267,8 @@ def propagate_block_ell(
 
     def build():
         donate_kw = {"donate_argnums": (0, 1)} if do_donate else {}
-        round_fn = functools.partial(
-            _prepared_round,
-            prep,
-            eps=cfg.eps_for(prep.d.val.dtype),
-            int_eps=cfg.int_eps,
-            inf=cfg.inf,
-            use_pallas=use_pallas,
-            fused=do_fuse,
-            scatter=scatter,
-            interpret=interpret,
-            slab=slab,
+        round_fn = round_fn_for(
+            prep, cfg, use_pallas, scatter, do_fuse, interpret, slab
         )
         if driver == "host_loop":
             # Progress is computed INSIDE the jit, where the pre-round
@@ -1450,7 +1446,7 @@ def prepare_problem_batch(batch: ProblemBatch, dtype=None) -> PreparedBatch:
     bounds -- ``propagate_batch_prepared`` takes them as per-call
     arguments)."""
     ell = batch.ell
-    dt = np.dtype(dtype) if dtype is not None else np.dtype(ell.val.dtype)
+    dt = resolve_dtype(dtype, ell.val.dtype)
     key = (id(batch), dt.str)
     hit = _batch_prep_cache.get(key, (batch,))
     if hit is not None:
@@ -1533,6 +1529,8 @@ def _batched_prepared_round(
     routed by ``(instance, slab)``, same gating); only buckets with rows
     spanning chunks at small ``n_pad`` use the batched jnp dataflow."""
     d = prep.d
+    if use_pallas:
+        interpret = kern.resolve_interpret(interpret, lb.dtype)
     if use_pallas and prep.fits_one_chunk and prep.n_pad <= SCATTER_MAX_NPAD:
         best_l, best_u = kern.batched_fused_scatter_round_tiles(
             d.val, d.col, d.ii_g, d.lhs_g, d.rhs_g, lb, ub,
@@ -2013,6 +2011,8 @@ def _node_round(
     single-instance jnp round is vmapped over the node axis (multichunk
     rows at small ``n_pad``, or ``use_pallas=False``), with inactive
     nodes' bounds frozen outside."""
+    if use_pallas:
+        interpret = kern.resolve_interpret(interpret, lb.dtype)
     if use_pallas and prep.fits_one_chunk and prep.n_pad <= SCATTER_MAX_NPAD:
         d = prep.d
         best_l, best_u = kern.node_fused_scatter_round_tiles(

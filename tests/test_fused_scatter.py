@@ -108,6 +108,68 @@ def test_in_kernel_gather_matches_xla_gather(rng):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-12, atol=1e-12)
 
 
+def _wide_f32(rng, shape, sign):
+    """float32 magnitudes across the range below the sentinel, 10% of
+    them the infinity sentinel itself."""
+    x = sign * 10.0 ** rng.uniform(-30, 19.9, size=shape)
+    x[rng.random(shape) < 0.1] = sign * INF
+    return x.astype(np.float32)
+
+
+def test_float32_gather_is_exact(rng):
+    """At float32 the gather's one-hot matmuls carry bounds as three
+    bfloat16 pieces; the gathered bounds must still be bitwise those of an
+    XLA gather, for magnitudes across the float32 range."""
+    from repro.kernels import activities_gather_tiles
+
+    t, r, k, n = 4, 8, 32, 300
+    n_pad = col_pad(n)
+    val = rng.choice([-2.5, -1.0, 0.0, 1.0, 3.0], size=(t, r, k)).astype(np.float32)
+    col = rng.integers(0, n, size=(t, r, k)).astype(np.int32)
+    lb, ub = _wide_f32(rng, n_pad, -1.0), _wide_f32(rng, n_pad, 1.0)
+    val, col, lb, ub = map(jnp.asarray, (val, col, lb, ub))
+    got = activities_gather_tiles(val, col, lb, ub, n_pad, interpret=True)
+    want = activities_tiles(val, lb[col], ub[col], interpret=True)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("repeat", [False, True], ids=["distinct", "repeated"])
+def test_float32_scatter_is_exact(rng, repeat):
+    """The scatter places float32 candidates through the MXU as bfloat16
+    pieces when a tile's rows hold distinct columns, and compares
+    otherwise; either way the accumulators are bitwise a segment max/min."""
+    from jax.experimental import pallas as pl
+
+    from repro.kernels import prop_round as kern
+
+    r, k, n = 8, 32, 300
+    n_pad = col_pad(n)
+    if repeat:
+        col = rng.integers(0, 8, size=(r, k))
+    else:
+        col = np.stack([rng.choice(n, size=k, replace=False) for _ in range(r)])
+    lc, uc = _wide_f32(rng, (r, k), -1.0), _wide_f32(rng, (r, k), 1.0)
+    lc[:, ::2] *= -1.0  # both signs on both sides
+    uc[:, ::3] *= -1.0
+
+    def kernel(lc_ref, uc_ref, col_ref, bl_ref, bu_ref):
+        kern._init_accumulators(bl_ref, bu_ref, INF)
+        kern._scatter_tile(lc_ref[...], uc_ref[...], col_ref[...], bl_ref, bu_ref,
+                           inf=INF, block=128)
+
+    plane = jax.ShapeDtypeStruct((n_pad // 128, 128), jnp.float32)
+    bl, bu = pl.pallas_call(
+        kernel, grid=(1,), out_shape=[plane, plane], interpret=True
+    )(jnp.asarray(lc), jnp.asarray(uc), jnp.asarray(col, jnp.int32))
+    want_l = np.full(n_pad, -INF, np.float32)
+    want_u = np.full(n_pad, INF, np.float32)
+    np.maximum.at(want_l, col.ravel(), lc.ravel())
+    np.minimum.at(want_u, col.ravel(), uc.ravel())
+    np.testing.assert_array_equal(np.asarray(bl).ravel(), want_l)
+    np.testing.assert_array_equal(np.asarray(bu).ravel(), want_u)
+
+
 def test_apply_updates_kernel_matches_shared_semantics(rng):
     n_pad = 128
     lb = jnp.asarray(rng.uniform(-5, 0, n_pad))

@@ -24,6 +24,8 @@ numpy-f64 sequential oracle (``core.seq_ref``), so they stay meaningful
 with ``jax_enable_x64`` off -- CI's fp32 leg runs exactly these (see
 ``conftest.pytest_collection_modifyitems``).
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -34,9 +36,11 @@ from repro.core import (
     INF,
     PropagationService,
     TierPolicy,
+    F32_BAND,
     bounds_equal,
     branch_children,
     int_round_slack,
+    overtightening,
     progress_measure,
     propagate,
     propagate_batch,
@@ -52,12 +56,6 @@ from repro.data import (
     make_set_cover,
 )
 from repro.kernels import prepare_block_ell, propagate_block_ell, round_cost_analysis
-
-# fp32-representation / cancellation band of the outward-rounded tier:
-# observed worst case 6.4e-8 relative ("mixed" family, cancellation-heavy
-# rows), asserted with ~15x headroom -- still 10x tighter than the paper's
-# bounds_equal criterion (t_rel=1e-5).
-F32_BAND = 1e-6
 
 
 def _population():
@@ -82,25 +80,10 @@ def _run_f32(engine, p):
 
 def _assert_never_tighter(name, lb_t, ub_t, lb_o, ub_o, is_int, band):
     """Tier bounds must stay outside the oracle's, up to ``band`` relative
-    for continuous variables and EXACTLY for integer ones."""
-    lb_t = np.asarray(lb_t, np.float64)
-    ub_t = np.asarray(ub_t, np.float64)
-    # An oracle-infinite bound the tier made finite is an unbounded
-    # overtightening -- never allowed.
-    assert not np.any((lb_o <= -INF / 2) & (lb_t > -INF / 2)), name
-    assert not np.any((ub_o >= INF / 2) & (ub_t < INF / 2)), name
-    fin_l = lb_o > -INF / 2
-    fin_u = ub_o < INF / 2
-    tol = np.where(is_int, 0.0, band * (1.0 + np.abs(lb_o)))
-    assert np.all(lb_t[fin_l] <= (lb_o + tol)[fin_l]), (
-        f"{name}: lb overtightened by "
-        f"{np.max((lb_t - lb_o - tol)[fin_l]):.3e}"
-    )
-    tol = np.where(is_int, 0.0, band * (1.0 + np.abs(ub_o)))
-    assert np.all(ub_t[fin_u] >= (ub_o - tol)[fin_u]), (
-        f"{name}: ub overtightened by "
-        f"{np.max((ub_o - tol - ub_t)[fin_u]):.3e}"
-    )
+    for continuous variables and EXACTLY for integer ones (an
+    oracle-infinite bound made finite is never allowed)."""
+    msg = overtightening(lb_t, ub_t, lb_o, ub_o, is_int, band)
+    assert msg is None, f"{name}: {msg}"
 
 
 # ---------------------------------------------------------------------------
@@ -134,6 +117,22 @@ def test_fp32_tier_never_tighter_than_f64_oracle(engine):
         # STATISTIC (the fp32-only fixed point may stop epsilon-weaker --
         # the paper reports 842/987, and benchmarks/precision.py accounts
         # the rate); the invariant tested here is only never-tighter.
+
+
+@pytest.mark.f32native
+@pytest.mark.parametrize("engine", ["fused", "segment"])
+def test_fp32_single_instance_merge_widens_outward(engine):
+    """``propagate_block_ell`` at fp32 merges with the outward widening of
+    its config: switching the widening off yields tighter bounds, so the
+    fixed point did use it (the same merge every other engine applies)."""
+    p = make_mixed(m=80, n=60, seed=0)
+    exact = dataclasses.replace(DEFAULT_CONFIG, outward_eps_f32=0.0)
+    wide = propagate_block_ell(p, dtype=np.float32, scatter=engine)
+    tight = propagate_block_ell(p, cfg=exact, dtype=np.float32, scatter=engine)
+    lw, uw = np.asarray(wide.lb), np.asarray(wide.ub)
+    lt, ut = np.asarray(tight.lb), np.asarray(tight.ub)
+    assert np.all(lw <= lt) and np.all(uw >= ut)
+    assert np.any(lw < lt) or np.any(uw > ut)
 
 
 @pytest.mark.f32native

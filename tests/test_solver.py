@@ -125,6 +125,31 @@ def test_differential_random_mip(seed):
     _check_accounting(res)
 
 
+@pytest.mark.parametrize(
+    "family,seed", [("pb", 0), ("pb", 1), ("pb", 3), ("mip", 0), ("mip", 1)]
+)
+def test_differential_tpu_defaults(monkeypatch, family, seed):
+    """``solve()`` as it resolves on a TPU -- Pallas kernels, float32 with
+    outward-rounded merges -- still matches the exhaustive oracle bitwise
+    (kernels in interpret mode here)."""
+    import jax
+
+    from repro.kernels import prop_round
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(prop_round, "_on_cpu", lambda: True)
+    p = (make_pseudo_boolean(n=12, m=16, seed=seed) if family == "pb"
+         else make_random_mip(n=9, m=12, seed=seed))
+    c = _objective(p)
+    bf = brute_force_solve(p, c)
+    res = solve(p, c, node_cap=64, max_levels=48)
+    assert res.feasible == bf.feasible
+    assert res.objective == bf.objective
+    if res.feasible:
+        assert float(c @ res.x) == bf.objective
+        _assert_solution_feasible(p, res.x)
+
+
 def test_infeasible_at_root():
     # x0 >= 1 and x0 <= 0: root propagation crosses the bounds immediately.
     dense = np.array([[1.0, 0.0], [1.0, 0.0], [0.0, 1.0]])
