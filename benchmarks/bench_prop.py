@@ -805,11 +805,10 @@ def _partitioned_phase_fns(prep, part):
     return copy_phase, reduce_phase, combine_phase, merge_phase
 
 
-def _partitioned_phase_times(prep, part, repeats: int = 3, tracer=None) -> dict:
+def _partitioned_phase_times(prep, part, repeats: int = 3) -> dict:
     """Per-phase wall times (us) of one partitioned round via
     ``obs.timing.time_phases``: each phase is fed the previous phase's
-    ready outputs and fenced at its boundary; a ``tracer`` additionally
-    emits one ``phase:<name>`` span per phase onto the shared trace."""
+    ready outputs and fenced at its boundary."""
     copy_f, reduce_f, combine_f, merge_f = _partitioned_phase_fns(prep, part)
     g = jax.block_until_ready
     lb, ub = prep.lb0, prep.ub0
@@ -825,7 +824,6 @@ def _partitioned_phase_times(prep, part, repeats: int = 3, tracer=None) -> dict:
             "merge": lambda: merge_f(*cands, gathered[4], lb, ub),
         },
         repeats=repeats,
-        tracer=tracer,
     )
 
 

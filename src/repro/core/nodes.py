@@ -25,6 +25,7 @@ from typing import NamedTuple, Sequence
 
 import numpy as np
 
+from ..obs.trace import PROGRAM
 from .sparse import Problem
 from .types import (
     DEFAULT_CONFIG,
@@ -153,6 +154,7 @@ def pick_most_fractional(lb, ub, is_int) -> "int | None":
     return int(np.argmax(score))
 
 
+@PROGRAM.traced("prop.nodes")
 def propagate_nodes(
     p: Problem,
     lb_nodes,
@@ -192,7 +194,12 @@ def propagate_nodes(
 
     ``telemetry`` (a ring capacity) carries a per-node device telemetry
     plane through the dispatch; read node trajectories via
-    ``result.node_telemetry(i)`` / ``result.result(i).telemetry``."""
+    ``result.node_telemetry(i)`` / ``result.result(i).telemetry``.
+
+    Each call is one ``prop.nodes`` span of the program tracer
+    (``obs.trace.PROGRAM``, attrs ``nodes`` and ``n_pad``), from entry to
+    return, holding the ``prop.prepare``, ``prop.upload`` (site ``nodes``,
+    the two planes) and ``prop.launch`` spans of each tier."""
     from ..kernels.ops import (  # lazy: kernels imports core at module scope
         prepare_block_ell,
         propagate_nodes_prepared,
@@ -205,6 +212,7 @@ def propagate_nodes(
         dt32, final = pair
         cap32 = max(1, int(cfg.max_rounds * policy.fp32_round_frac))
         prep32 = prepare_block_ell(p, tile_rows, tile_width, dt32)
+        PROGRAM.note(nodes=len(lb_nodes), n_pad=prep32.n_pad)
         out32 = propagate_nodes_prepared(
             prep32, lb_nodes, ub_nodes,
             dataclasses.replace(cfg, max_rounds=cap32),
@@ -244,6 +252,7 @@ def propagate_nodes(
         stop_progress = policy.stop_progress
         patience = policy.patience
     prep = prepare_block_ell(p, tile_rows, tile_width, dtype)
+    PROGRAM.note(nodes=len(lb_nodes), n_pad=prep.n_pad)
     out = propagate_nodes_prepared(
         prep, lb_nodes, ub_nodes, cfg,
         use_pallas=use_pallas, interpret=interpret, donate=donate, slab=slab,

@@ -390,30 +390,33 @@ def batched_step_rounds(
         lb, ub, active, last_changed, rounds, progress, flat, k = state[:8]
         lb_in, ub_in = lb, ub
         ran = active
-        lb, ub, changed = round_fn(lb, ub, active)
-        rounds = rounds + active.astype(jnp.int32)
-        last_changed = jnp.where(active, changed, last_changed)
-        if track:
-            prog = bnd.progress_measure(lb_in, ub_in, lb, ub)
-            progress = jnp.where(active, prog, progress)
+        with jax.named_scope("prop_round"):
+            lb, ub, changed = round_fn(lb, ub, active)
+        with jax.named_scope("fixed_point"):
+            rounds = rounds + active.astype(jnp.int32)
+            last_changed = jnp.where(active, changed, last_changed)
+            if track:
+                prog = bnd.progress_measure(lb_in, ub_in, lb, ub)
+                progress = jnp.where(active, prog, progress)
+                if stop_progress is not None:
+                    flat = jnp.where(
+                        active, jnp.where(prog < stop_progress, flat + 1, 0), flat
+                    )
+            active = active & changed & (rounds < max_rounds)
             if stop_progress is not None:
-                flat = jnp.where(
-                    active, jnp.where(prog < stop_progress, flat + 1, 0), flat
+                active = active & (flat < patience)
+            out = (lb, ub, active, last_changed, rounds, progress, flat, k + 1)
+            if plane is not None:
+                stopped = (flat >= patience) if stop_progress is not None else None
+                tel = obs.record_round(
+                    state[8], prog,
+                    rounds, jnp.any(lb > ub + feas_eps, axis=-1), stopped,
+                    active=ran,
                 )
-        active = active & changed & (rounds < max_rounds)
-        if stop_progress is not None:
-            active = active & (flat < patience)
-        out = (lb, ub, active, last_changed, rounds, progress, flat, k + 1)
-        if plane is not None:
-            stopped = (flat >= patience) if stop_progress is not None else None
-            tel = obs.record_round(
-                state[8], prog,
-                rounds, jnp.any(lb > ub + feas_eps, axis=-1), stopped,
-                active=ran,
-            )
-            out = out + (tel,)
+                out = out + (tel,)
         return out
 
+    @jax.named_scope("fixed_point")
     def cond(state):
         go = jnp.any(state[2])
         if budget is not None:

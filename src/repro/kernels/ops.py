@@ -91,6 +91,7 @@ from ..core.types import (
     resolve_dtype,
 )
 from ..obs import telemetry as obs
+from ..obs.trace import PROGRAM
 from . import prop_round as kern
 from . import ref as kref
 
@@ -117,25 +118,40 @@ class DeviceBlockEll(NamedTuple):
     ub0: jnp.ndarray        # (n,)
 
 
+def _upload(site: str, *pairs) -> tuple:
+    """``jnp.asarray(a, dtype)`` for each ``(a, dtype)`` of ``pairs``.
+    The host arrays among them are copied to the device under one
+    ``prop.upload`` span whose ``bytes`` (what the device receives) also
+    count under the program's ``h2d_bytes`` counter at ``site``.  ``None``
+    stays ``None``, a device array is only cast, and with no host array
+    there is no span."""
+    host = [a is not None and not isinstance(a, jax.Array) for a, _ in pairs]
+    if not any(host):
+        return tuple(a if a is None else jnp.asarray(a, dt) for a, dt in pairs)
+    with PROGRAM.span("prop.upload", site=site) as attrs:
+        out = tuple(a if a is None else jnp.asarray(a, dt) for a, dt in pairs)
+        nbytes = sum(int(o.nbytes) for o, h in zip(out, host) if h)
+        attrs["bytes"] = nbytes
+        PROGRAM.add("h2d_bytes", site, nbytes)
+    return out
+
+
 def device_block_ell(p: Problem, tile_rows: int = 8, tile_width: int = 128, dtype=None) -> DeviceBlockEll:
     """Convert + upload one instance: block-ELL tiles of shape
     ``(tile_rows, tile_width)``, sides padded with a dummy slot for the
-    padding row, bounds and integrality marks as ``(n,)`` device arrays.
+    padding row, bounds and integrality marks as ``(n,)`` device arrays
+    (one ``prop.upload`` span, site ``prepare``).
     Prefer :func:`prepare_block_ell`, which caches this and hoists the
     round-constant gathers."""
     dtype = resolve_dtype(dtype, p.csr.val.dtype)
     b = csr_to_block_ell(p.csr, tile_rows=tile_rows, tile_width=tile_width)
     pad1 = lambda x: np.concatenate([x, np.zeros(1, dtype=x.dtype)])
-    return DeviceBlockEll(
-        val=jnp.asarray(b.val, dtype=dtype),
-        col=jnp.asarray(b.col),
-        chunk_row=jnp.asarray(b.chunk_row),
-        lhs1=jnp.asarray(pad1(p.lhs), dtype=dtype),
-        rhs1=jnp.asarray(pad1(p.rhs), dtype=dtype),
-        is_int=jnp.asarray(p.is_int),
-        lb0=jnp.asarray(p.lb, dtype=dtype),
-        ub0=jnp.asarray(p.ub, dtype=dtype),
-    )
+    return DeviceBlockEll(*_upload(
+        "prepare",
+        (b.val, dtype), (b.col, None), (b.chunk_row, None),
+        (pad1(p.lhs), dtype), (pad1(p.rhs), dtype), (p.is_int, None),
+        (p.lb, dtype), (p.ub, dtype),
+    ))
 
 
 def rows_fit_one_chunk(p: Problem, tile_width: int) -> bool:
@@ -684,6 +700,7 @@ def _drop_runners_for(anchors, value) -> None:
 _prep_cache = LRU(maxsize=32, on_evict=_drop_runners_for)
 
 
+@PROGRAM.traced("prop.prepare")
 def prepare_block_ell(
     p: Problem, tile_rows: int = 8, tile_width: int = 128, dtype=None
 ) -> PreparedBlockEll:
@@ -699,12 +716,15 @@ def prepare_block_ell(
     live; a hit from a problem whose bounds differ from the cached defaults
     returns a cheap bounds-swapped view sharing every device tile.
     ``dtype=None`` is the instance's own dtype, float32 on a TPU
-    (:func:`~repro.core.types.resolve_dtype`).
+    (:func:`~repro.core.types.resolve_dtype`).  Each call is one
+    ``prop.prepare`` span of the program tracer, ``hit`` telling a cache
+    hit from a build.
     """
     dt = resolve_dtype(dtype, p.csr.val.dtype)
     anchors = _structure_anchors(p)
     key = tuple(id(a) for a in anchors) + (tile_rows, tile_width, dt.str)
     hit = _prep_cache.get(key, anchors)
+    PROGRAM.note(hit=hit is not None)
     if hit is not None:
         creator, prep = hit
         if creator.lb is p.lb and creator.ub is p.ub:
@@ -715,10 +735,9 @@ def prepare_block_ell(
         # legacy readers of d.lb0 cannot silently see the creator's domain.
         # Runner caches key on id(d.val) (stable across _replace), so the
         # view reuses the creator's compiled fixed points.
-        lb0, ub0 = prep.pad_bounds(p.lb, p.ub)
-        d = prep.d._replace(
-            lb0=jnp.asarray(p.lb, dt), ub0=jnp.asarray(p.ub, dt)
-        )
+        lb, ub = _upload("prepare", (p.lb, dt), (p.ub, dt))
+        lb0, ub0 = prep.pad_bounds(lb, ub)
+        d = prep.d._replace(lb0=lb, ub0=ub)
         return dataclasses.replace(prep, d=d, lb0=lb0, ub0=ub0)
 
     d = device_block_ell(p, tile_rows, tile_width, dt)
@@ -1144,12 +1163,16 @@ _runner_cache = LRU(maxsize=64)
 
 
 def _initial_padded_bounds(prep: PreparedBlockEll, lb0, ub0):
-    """Per-call bound overrides -> private, donated-safe (n_pad,) buffers."""
+    """Per-call bound overrides -> private, donated-safe (n_pad,) buffers
+    (host overrides uploaded under site ``bounds``)."""
+    dt = prep.d.val.dtype
+    lb0, ub0 = _upload("bounds", (lb0, dt), (ub0, dt))
     lb = owned_copy(prep.lb0 if lb0 is None else prep.pad_bound(lb0))
     ub = owned_copy(prep.ub0 if ub0 is None else prep.pad_bound(ub0))
     return lb, ub
 
 
+@PROGRAM.traced("prop.presolve")
 def propagate_block_ell(
     p: Problem,
     cfg: PropagatorConfig = DEFAULT_CONFIG,
@@ -1199,7 +1222,14 @@ def propagate_block_ell(
     back only at exit.  Recording reuses the progress scalar the carry
     already computes, so bounds stay bitwise identical and the fixed point
     remains one dispatch; the telemetry capacity is part of the runner
-    cache key (on/off are distinct compiled runners, each cached once)."""
+    cache key (on/off are distinct compiled runners, each cached once).
+
+    Each call is one ``prop.presolve`` span of the program tracer
+    (``obs.trace.PROGRAM``, attrs ``engine`` and ``n_pad``), from entry to
+    return: the launch is asynchronous and nothing waits for the device.
+    Inside it are the ``prop.prepare``, ``prop.upload`` and ``prop.launch``
+    spans (``built`` on a runner-cache miss); a two-tier call nests one
+    ``prop.presolve`` span per tier."""
     if driver not in ("host_loop", "device_loop"):
         raise ValueError(f"unknown driver: {driver!r}")
     tel_cap = int(telemetry or 0)
@@ -1256,6 +1286,7 @@ def propagate_block_ell(
         prep.fits_one_chunk if fused == "auto" else bool(fused == "yes" or fused is True)
     )
     scatter = _resolve_scatter(scatter, prep)
+    PROGRAM.note(engine=scatter, n_pad=prep.n_pad)
     do_donate = donate_supported() if donate is None else bool(donate)
     n = prep.n
 
@@ -1273,34 +1304,39 @@ def propagate_block_ell(
         if driver == "host_loop":
             # Progress is computed INSIDE the jit, where the pre-round
             # bounds are still live (they are donated away by the call).
-            def step(lb, ub):
-                nlb, nub, ch = round_fn(lb, ub)
-                out = nlb, nub, ch, bnd.progress_measure(lb, ub, nlb, nub)
-                if tel_cap:
-                    out = out + (jnp.any(nlb > nub + cfg.feas_eps),)
+            def round_presolve(lb, ub):
+                with jax.named_scope("prop_round"):
+                    nlb, nub, ch = round_fn(lb, ub)
+                with jax.named_scope("fixed_point"):
+                    out = nlb, nub, ch, bnd.progress_measure(lb, ub, nlb, nub)
+                    if tel_cap:
+                        out = out + (jnp.any(nlb > nub + cfg.feas_eps),)
                 return out
 
-            return jax.jit(step, **donate_kw)
+            return jax.jit(round_presolve, **donate_kw)
 
         @functools.partial(jax.jit, **donate_kw)
-        def run(lb0, ub0):
+        def fixed_point_presolve(lb0, ub0):
             def body(state):
                 lb, ub, _, r, _, flat = state[:6]
-                nlb, nub, ch = round_fn(lb, ub)
-                prog = bnd.progress_measure(lb, ub, nlb, nub)
-                if stop_progress is not None:
-                    flat = jnp.where(prog < stop_progress, flat + 1, jnp.int32(0))
-                out = (nlb, nub, ch, r + 1, prog, flat)
-                if tel_cap:
-                    stopped = (
-                        (flat >= patience) if stop_progress is not None else None
-                    )
-                    out = out + (obs.record_round(
-                        state[6], prog, r + 1,
-                        jnp.any(nlb > nub + cfg.feas_eps), stopped,
-                    ),)
+                with jax.named_scope("prop_round"):
+                    nlb, nub, ch = round_fn(lb, ub)
+                with jax.named_scope("fixed_point"):
+                    prog = bnd.progress_measure(lb, ub, nlb, nub)
+                    if stop_progress is not None:
+                        flat = jnp.where(prog < stop_progress, flat + 1, jnp.int32(0))
+                    out = (nlb, nub, ch, r + 1, prog, flat)
+                    if tel_cap:
+                        stopped = (
+                            (flat >= patience) if stop_progress is not None else None
+                        )
+                        out = out + (obs.record_round(
+                            state[6], prog, r + 1,
+                            jnp.any(nlb > nub + cfg.feas_eps), stopped,
+                        ),)
                 return out
 
+            @jax.named_scope("fixed_point")
             def cond(state):
                 ch, r, flat = state[2], state[3], state[5]
                 go = ch & (r < cfg.max_rounds)
@@ -1320,16 +1356,23 @@ def propagate_block_ell(
             res = (lb, ub, r, ~ch, jnp.any(lb > ub + cfg.feas_eps), prog)
             return res + ((final[6],) if tel_cap else ())
 
-        return run
-
-    runner = _runner_cache.get(key, anchors)
-    if runner is None:
-        runner = build()
-        _runner_cache.put(key, anchors, runner)
+        return fixed_point_presolve
 
     lb, ub = _initial_padded_bounds(prep, lb0, ub0)
+    with PROGRAM.span("prop.launch") as launch:
+        runner = _runner_cache.get(key, anchors)
+        launch["built"] = runner is None
+        if runner is None:
+            runner = build()
+            _runner_cache.put(key, anchors, runner)
+        if driver == "device_loop":
+            out = runner(lb, ub)
+            lb, ub, rounds, converged, infeasible, prog = out[:6]
+            snap = obs.TelemetrySnapshot(plane=out[6]) if tel_cap else None
+            return PropagationResult(
+                lb, ub, rounds, converged, infeasible, progress=prog, telemetry=snap
+            )
 
-    if driver == "host_loop":
         rounds, changed, flat = 0, True, 0
         prog = jnp.asarray(jnp.nan, lb.dtype)
         history: list[float] = []
@@ -1357,13 +1400,6 @@ def propagate_block_ell(
             lb[:n], ub[:n], jnp.int32(rounds), jnp.asarray(not changed),
             jnp.asarray(infeas), progress=prog, telemetry=snap,
         )
-
-    out = runner(lb, ub)
-    lb, ub, rounds, converged, infeasible, prog = out[:6]
-    snap = obs.TelemetrySnapshot(plane=out[6]) if tel_cap else None
-    return PropagationResult(
-        lb, ub, rounds, converged, infeasible, progress=prog, telemetry=snap
-    )
 
 
 # ---------------------------------------------------------------------------
@@ -2091,11 +2127,13 @@ def node_batch_runner(
     telemetry: int | None = None,
 ):
     """The node batch's whole fixed point as ONE jitted dispatch, cached:
-    ``run(lb0, ub0) -> (lb, ub, rounds, converged, infeasible, progress)``
-    with the node axis leading everywhere (``lb0``/``ub0`` donated where
-    supported).  ``stop_progress``/``patience`` arm the per-node
-    progress-based early stop inside the dispatch; ``telemetry`` (a ring
-    capacity) appends the per-node ``obs.TelemetryPlane`` to the return."""
+    ``fixed_point_nodes(lb0, ub0) -> (lb, ub, rounds, converged,
+    infeasible, progress)`` with the node axis leading everywhere
+    (``lb0``/``ub0`` donated where supported).  ``stop_progress``/
+    ``patience`` arm the per-node progress-based early stop inside the
+    dispatch; ``telemetry`` (a ring capacity) appends the per-node
+    ``obs.TelemetryPlane`` to the return.  Notes ``built`` (a cache miss)
+    on the program tracer's open span."""
     do_donate = donate_supported() if donate is None else bool(donate)
     tel_cap = int(telemetry or 0)
     key = (
@@ -2104,6 +2142,7 @@ def node_batch_runner(
     )
     anchors = (prep.d.val,)
     runner = _node_runner_cache.get(key, anchors)
+    PROGRAM.note(built=runner is None)
     if runner is not None:
         return runner
 
@@ -2112,7 +2151,7 @@ def node_batch_runner(
     col_valid = jnp.arange(prep.n_pad) < prep.n
 
     @functools.partial(jax.jit, **donate_kw)
-    def run(lb0, ub0):
+    def fixed_point_nodes(lb0, ub0):
         plane = (
             obs.device_plane(tel_cap, batch=lb0.shape[0], dtype=lb0.dtype)
             if tel_cap else None
@@ -2123,12 +2162,13 @@ def node_batch_runner(
             plane=plane, feas_eps=cfg.feas_eps,
         )
         lb, ub, rounds, converged, progress = out[:5]
-        infeasible = jnp.any((lb > ub + cfg.feas_eps) & col_valid[None, :], axis=-1)
+        with jax.named_scope("fixed_point"):
+            infeasible = jnp.any((lb > ub + cfg.feas_eps) & col_valid[None, :], axis=-1)
         res = (lb, ub, rounds, converged, infeasible, progress)
         return res + ((out[5],) if tel_cap else ())
 
-    _node_runner_cache.put(key, anchors, run)
-    return run
+    _node_runner_cache.put(key, anchors, fixed_point_nodes)
+    return fixed_point_nodes
 
 
 def propagate_nodes_prepared(
@@ -2159,7 +2199,10 @@ def propagate_nodes_prepared(
     produce, including round counts.  ``telemetry`` (a ring capacity)
     appends the per-node batched ``obs.TelemetryPlane`` to either return
     shape -- wrap rows in ``obs.TelemetrySnapshot(plane, index=i)`` to
-    read one node's trajectory."""
+    read one node's trajectory.
+
+    The two planes go up under one ``prop.upload`` span (site ``nodes``);
+    runner lookup and dispatch are one ``prop.launch`` span."""
     lb_nodes = np.asarray(lb_nodes)
     ub_nodes = np.asarray(ub_nodes)
     if lb_nodes.ndim != 2 or lb_nodes.shape != ub_nodes.shape:
@@ -2177,13 +2220,15 @@ def propagate_nodes_prepared(
         plane = np.asarray(plane, dt)
         if pad:
             plane = np.concatenate([plane, np.zeros((bsz, pad), dt)], axis=1)
-        planes.append(jnp.asarray(plane))
+        planes.append((plane, None))
+    planes = _upload("nodes", *planes)
     tel_cap = int(telemetry or 0)
-    run = node_batch_runner(
-        prep, bsz, cfg, use_pallas, interpret, donate, slab,
-        stop_progress, patience, telemetry=tel_cap,
-    )
-    res = run(*planes)
+    with PROGRAM.span("prop.launch"):
+        run = node_batch_runner(
+            prep, bsz, cfg, use_pallas, interpret, donate, slab,
+            stop_progress, patience, telemetry=tel_cap,
+        )
+        res = run(*planes)
     lb, ub, rounds, converged, infeasible, progress = res[:6]
     out = (lb[:, : prep.n], ub[:, : prep.n], rounds, converged, infeasible)
     if with_progress:

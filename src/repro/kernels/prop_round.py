@@ -58,9 +58,9 @@ Slab-parallel partitioned kernels (``n_pad > SCATTER_MAX_NPAD``):
   * ``_batched_slab_partials_kernel`` / ``_node_slab_partials_kernel`` --
         activity partials for the few STRADDLE rows whose nonzeros are
         split across slab copies (completed by a tiny segment sum outside).
-  * ``_apply_updates_slab_kernel``  -- standalone slab-windowed merge
-        (kept for callers composing their own partitioned pipelines; the
-        round kernels above merge in place themselves).
+  * ``_apply_updates_gated_kernel`` -- standalone slab-windowed or
+        batched merge (kept for callers composing their own partitioned
+        pipelines; the round kernels above merge in place themselves).
 
 In the fused engine the irregular gather itself moves into the kernels
 (``_gather_bounds_tile``): the bound vectors ride along as VMEM-resident
@@ -68,6 +68,9 @@ In the fused engine the irregular gather itself moves into the kernels
 during a round -- per grid step HBM only streams the tile's static matrix
 data.  Kernels are validated on CPU via ``interpret=True`` against
 ``ref.py``.
+
+Every ``pallas_call`` is named from :data:`KERNEL_NAMES` (``prop_<kernel>``),
+so each kernel keeps its name on the device and in a profiler trace.
 
 Mosaic layout rules (what makes every kernel compile for a TPU)
 ----------------------------------------------------------------
@@ -110,6 +113,30 @@ from ..core.types import (
 _I0 = np.int32(0)
 # One-hot constants: Python floats would trace as float64 under x64.
 _ONE, _ZERO = np.float32(1.0), np.float32(0.0)
+
+#: The device name of each kernel, keyed by the kernel body it runs
+#: (``_<key>_kernel``; the two batched slab kernels drop ``batched_``).
+#: Every ``pallas_call`` passes its kernel's name as ``name=``, so the HLO
+#: custom call, and the op a profiler trace lists, is ``%<name>.N`` rather
+#: than the name of the enclosing scope.  Two call sites of one kernel
+#: share its name.
+KERNEL_NAMES = {
+    "activities": "prop_activities",
+    "activities_gather": "prop_activities_gather",
+    "candidates": "prop_candidates",
+    "fused_round": "prop_fused_round",
+    "fused_scatter": "prop_fused_scatter",
+    "candidates_scatter": "prop_candidates_scatter",
+    "apply_updates": "prop_apply_updates",
+    "batched_fused_scatter": "prop_batched_fused_scatter",
+    "node_fused_scatter": "prop_node_fused_scatter",
+    "slab_partials": "prop_slab_partials",
+    "slab_round": "prop_slab_round",
+    "node_slab_partials": "prop_node_slab_partials",
+    "node_slab_round": "prop_node_slab_round",
+    "apply_updates_gated": "prop_apply_updates_gated",
+    "node_objective": "prop_node_objective",
+}
 
 
 def _on_cpu() -> bool:
@@ -333,6 +360,7 @@ def activities_tiles(val, lb_g, ub_g, inf: float = INF, interpret: bool | None =
         in_specs=[tile, tile, tile],
         out_specs=[_row_spec(r)] * 4,
         out_shape=_partials_shapes((t,), r, val.dtype),
+        name=KERNEL_NAMES["activities"],
         interpret=interpret,
     )
     return tuple(x.reshape(t, r) for x in fn(val, lb_g, ub_g))
@@ -375,6 +403,7 @@ def activities_gather_tiles(
         in_specs=[tile, tile, vec, vec],
         out_specs=[_row_spec(r)] * 4,
         out_shape=_partials_shapes((t,), r, val.dtype),
+        name=KERNEL_NAMES["activities_gather"],
         interpret=interpret,
     )
     out = fn(val, col, _lane_rows(lb, block), _lane_rows(ub, block))
@@ -428,6 +457,7 @@ def candidates_tiles(
         in_specs=[tile] * 4 + [_row_spec(r)] * 6,
         out_specs=[tile, tile],
         out_shape=out_shape,
+        name=KERNEL_NAMES["candidates"],
         interpret=interpret,
     )
     rows = (row_min_fin, row_min_cnt, row_max_fin, row_max_cnt, lhs_g, rhs_g)
@@ -477,6 +507,7 @@ def fused_round_tiles(
         in_specs=[tile] * 4 + [_row_spec(r)] * 2,
         out_specs=[tile, tile],
         out_shape=out_shape,
+        name=KERNEL_NAMES["fused_round"],
         interpret=interpret,
     )
     return fn(
@@ -727,6 +758,7 @@ def fused_scatter_round_tiles(
         in_specs=[tile, tile, tile, row, row, vec, vec],
         out_specs=[vec, vec],
         out_shape=out_shape,
+        name=KERNEL_NAMES["fused_scatter"],
         interpret=interpret,
     )
     best_l, best_u = fn(
@@ -791,6 +823,7 @@ def candidates_scatter_tiles(
         in_specs=[tile] * 3 + [_row_spec(r)] * 6 + [vec, vec],
         out_specs=[vec, vec],
         out_shape=out_shape,
+        name=KERNEL_NAMES["candidates_scatter"],
         interpret=interpret,
     )
     rows = (row_min_fin, row_min_cnt, row_max_fin, row_max_cnt, lhs_g, rhs_g)
@@ -863,6 +896,7 @@ def apply_updates_tiles(
         out_specs=[vec, vec, pl.BlockSpec((1, 1), lambda: (_I0, _I0))],
         out_shape=out_shape,
         input_output_aliases={0: 0, 1: 1},
+        name=KERNEL_NAMES["apply_updates"],
         interpret=interpret,
     )
     new_lb, new_ub, changed = fn(*planes)
@@ -984,6 +1018,7 @@ def batched_fused_scatter_round_tiles(
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        name=KERNEL_NAMES["batched_fused_scatter"],
         interpret=interpret,
     )
     best_l, best_u = fn(
@@ -1114,6 +1149,7 @@ def node_fused_scatter_round_tiles(
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        name=KERNEL_NAMES["node_fused_scatter"],
         interpret=interpret,
     )
     best_l, best_u = fn(
@@ -1277,6 +1313,7 @@ def batched_slab_partials_tiles(
         functools.partial(_batched_slab_partials_kernel, inf=inf, block=block),
         grid_spec=grid_spec,
         out_shape=_partials_shapes((t,), r, val.dtype),
+        name=KERNEL_NAMES["slab_partials"],
         interpret=interpret,
         compiler_params=_dims("parallel", "arbitrary"),
     )
@@ -1426,6 +1463,7 @@ def batched_slab_round_tiles(
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        name=KERNEL_NAMES["slab_round"],
         interpret=interpret,
         compiler_params=_dims("parallel", "arbitrary"),
     )
@@ -1505,6 +1543,7 @@ def node_slab_partials_tiles(
         functools.partial(_node_slab_partials_kernel, inf=inf, block=block),
         grid_spec=grid_spec,
         out_shape=_partials_shapes((bsz, t), r, val.dtype),
+        name=KERNEL_NAMES["node_slab_partials"],
         interpret=interpret,
         compiler_params=_dims("parallel", "parallel", "arbitrary"),
     )
@@ -1612,6 +1651,7 @@ def node_slab_round_tiles(
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        name=KERNEL_NAMES["node_slab_round"],
         interpret=interpret,
         compiler_params=_dims("parallel", "parallel", "arbitrary"),
     )
@@ -1685,6 +1725,7 @@ def apply_updates_slab_tiles(
         out_specs=[vec, vec, flag_out],
         out_shape=out_shape,
         input_output_aliases={0: 0, 1: 1},
+        name=KERNEL_NAMES["apply_updates_gated"],
         interpret=interpret,
         compiler_params=_dims("parallel", "parallel"),
     )
@@ -1733,6 +1774,7 @@ def apply_updates_batch_tiles(
         out_specs=[vec, vec, flag],
         out_shape=out_shape,
         input_output_aliases={0: 0, 1: 1},
+        name=KERNEL_NAMES["apply_updates_gated"],
         interpret=interpret,
     )
     new_lb, new_ub, changed = fn(
@@ -1807,6 +1849,7 @@ def node_objective_tiles(
         in_specs=[vec, vec, const, const, const],
         out_specs=[flag, flag, flag],
         out_shape=out_shape,
+        name=KERNEL_NAMES["node_objective"],
         interpret=interpret,
     )
     obj, fixed, crossed = fn(*planes, *shared)

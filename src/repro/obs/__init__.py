@@ -8,9 +8,10 @@ Three halves, one subsystem (docs/OBSERVABILITY.md):
   back only where the host already syncs.  Telemetry-on is bitwise
   identical to telemetry-off by construction.
 * ``obs.trace`` -- the HOST half: a :class:`Tracer` of structured spans
-  (service pump/admit/readback, per-ticket lifecycles, engine phase
-  splits) exported as schema-pinned JSON-lines, with optional
-  ``jax.profiler`` trace annotations.
+  in a bounded ring, each also a ``jax.profiler`` trace annotation, with
+  per-name totals and per-site counters; :data:`PROGRAM` is the program's
+  own, always on (entry points, uploads, launches), and the service takes
+  one for its pump/admit/readback cycle and per-ticket lifecycles.
 * ``obs.metrics`` -- the AGGREGATION half: a :class:`MetricsRegistry`
   putting every ad-hoc source (LRU cache_info, compile counts, fill
   histograms, service counters) behind one pinned-schema ``snapshot()``,
@@ -44,7 +45,9 @@ from .timing import (
     time_phases,
 )
 from .trace import (
+    DEFAULT_SPAN_CAPACITY,
     NULL_TRACER,
+    PROGRAM,
     SPAN_KEYS,
     SPAN_SCHEMA_VERSION,
     NullTracer,
@@ -54,9 +57,11 @@ from .trace import (
 
 __all__ = [
     "DEFAULT_CAPACITY",
+    "DEFAULT_SPAN_CAPACITY",
     "MetricsRegistry",
     "NULL_TRACER",
     "NullTracer",
+    "PROGRAM",
     "SNAPSHOT_KEYS",
     "SNAPSHOT_SCHEMA_VERSION",
     "SPAN_KEYS",

@@ -83,13 +83,18 @@ class MetricsRegistry:
 
 def default_registry() -> MetricsRegistry:
     """Registry preloaded with the process-wide sources every run has:
-    kernel LRU ``cache_info()`` and the service engine cache.  Callers
-    (the service constructor, the bench's ``obs`` row) add their own
-    instance-scoped sources on top."""
+    kernel LRU ``cache_info()`` and the program tracer's totals
+    (``program``: per-span-name counts and seconds, per-site counters such
+    as ``h2d_bytes``).  Callers (the service constructor, the bench's
+    ``obs`` row) add their own instance-scoped sources on top."""
     from ..kernels.ops import cache_info  # lazy: kernels pulls in jax state
+    from .trace import PROGRAM
 
     reg = MetricsRegistry()
     reg.register("kernel_caches", cache_info)
+    reg.register(
+        "program", lambda: {"spans": PROGRAM.totals(), "counters": PROGRAM.counters()}
+    )
     return reg
 
 

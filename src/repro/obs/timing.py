@@ -73,21 +73,13 @@ def median_ratio(trials, num: int = 0, den: int = 1) -> float:
     return statistics.median(t[num] / t[den] for t in trials)
 
 
-def time_phases(phases, repeats: int = 3, warmup: int = 1, tracer=None) -> dict:
+def time_phases(phases, repeats: int = 3, warmup: int = 1) -> dict:
     """Time named zero-arg callables: ``{name: microseconds}``.
 
     The partitioned bench's phase breakdown (copy/reduce/combine/merge)
     in one call: each phase is fenced and best-of timed independently.
-    When a ``tracer`` (``obs.trace.Tracer``) is given, each phase's timed
-    region is also emitted as a span named ``phase:<name>``, putting the
-    engine's phase split on the same trace as the service spans.
     """
-    out = {}
-    for name, fn in dict(phases).items():
-        if tracer is not None:
-            with tracer.span(f"phase:{name}", repeats=repeats):
-                t = time_fenced(fn, repeats=repeats, warmup=warmup)
-        else:
-            t = time_fenced(fn, repeats=repeats, warmup=warmup)
-        out[name] = t * 1e6
-    return out
+    return {
+        name: time_fenced(fn, repeats=repeats, warmup=warmup) * 1e6
+        for name, fn in dict(phases).items()
+    }

@@ -1,31 +1,41 @@
 """Host-side span tracing: structured timing for everything OUTSIDE the jit.
 
 The device half of observability (``obs.telemetry``) rides the while_loop;
-this module covers the host half -- the service's pump/admit/readback
-cycle, per-ticket submit->admit->steps->retire lifecycles, and the
-partitioned engine's copy/reduce/combine/merge phase timings (via
-``obs.timing.time_phases``, which emits one span per phase and replaces
-the bespoke fencing code the benches used to duplicate).
+this module covers the host half: the program's entry points (the
+process-wide :data:`PROGRAM` tracer, always on), and the service's
+pump/admit/readback cycle and per-ticket lifecycles (a tracer the caller
+hands the service).
 
-Spans are plain records with a pinned schema (:data:`SPAN_KEYS`), exported
-as JSON-lines by :meth:`Tracer.export` -- one object per line, trivially
-grep-able and loadable into pandas/Perfetto tooling.  ``annotate=True``
-additionally wraps each ``span()`` region in a ``jax.profiler``
-TraceAnnotation so the same names show up on the device timeline when a
-profiler trace is being captured (see docs/OBSERVABILITY.md).
+Every ``span()`` also opens a ``jax.profiler.TraceAnnotation`` carrying
+the span's ``span_id`` (and ``parent_id``) as metadata, so while a
+profiler trace is being captured each span lands in the same
+``.xplane.pb`` as the device operations, on the same clock; the
+JSON-lines export (perf-counter clock) joins that trace on ``span_id``.
+With no profiler running the annotation is a no-op.  Intervals logged
+after the fact with ``record()`` cannot be annotated and live in the
+export only.
 
-A :class:`NullTracer` stands in when tracing is off: every call is a
+Spans are plain records with a pinned schema (:data:`SPAN_KEYS`), kept in
+a bounded ring: the oldest drop once ``capacity`` is reached, while the
+per-name totals (count, seconds) and the per-site counters keep counting
+(see docs/OBSERVABILITY.md).
+
+A :class:`NullTracer` stands in where tracing is off: every call is a
 no-op, so instrumented hot paths pay one attribute lookup, not an if-tree.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
+import functools
 import io
 import itertools
 import json
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 #: Pinned span schema: every exported JSON line has exactly these keys.
 SPAN_KEYS = frozenset(
@@ -35,8 +45,11 @@ SPAN_KEYS = frozenset(
 #: Schema version stamped into exports (bump on any SPAN_KEYS change).
 SPAN_SCHEMA_VERSION = 1
 
+#: Spans a tracer's ring holds by default.
+DEFAULT_SPAN_CAPACITY = 1 << 14
 
-@dataclasses.dataclass
+
+@dataclasses.dataclass(slots=True)
 class Span:
     """One completed span: a named ``[t_start, t_end]`` interval + attrs."""
 
@@ -63,22 +76,32 @@ class Span:
 
 
 class Tracer:
-    """Collects spans; thread-safe; nesting tracked per thread.
+    """Collects spans in a bounded ring; thread-safe; nesting per thread.
 
-    ``span(name, **attrs)`` is the context-manager form (times the block,
-    parents nested spans); ``record(name, t_start, t_end, **attrs)`` logs
-    an interval whose endpoints were captured elsewhere -- the service uses
-    it to emit one ``ticket`` span per request at retirement from the
-    timestamps the ticket already carries, with zero tracing work on the
-    submit path.
+    ``span(name, **attrs)`` is the context-manager form: it times the
+    block, parents the spans opened inside it, and yields its ``attrs``
+    dict so the block can add what it learns on the way (a cache hit, the
+    bytes it moved); ``traced(name)`` makes a function's every call one
+    span, and ``note(**attrs)`` adds to the innermost open span from
+    inside it.  ``record(name, t_start, t_end, **attrs)`` logs an
+    interval whose endpoints were captured elsewhere -- the service uses it
+    to emit one ``ticket`` span per request at retirement from the
+    timestamps the ticket already carries.  ``add(counter, site, value)``
+    counts at the same boundaries (for example bytes moved, by call site).
+
+    The ring keeps the newest ``capacity`` spans; :meth:`totals` and
+    :meth:`counters` count every span and every addition since the last
+    :meth:`clear`.
     """
 
-    def __init__(self, annotate: bool = False, clock=time.perf_counter):
-        self._spans: list[Span] = []
+    def __init__(self, capacity: int = DEFAULT_SPAN_CAPACITY, clock=time.perf_counter):
+        self.capacity = int(capacity)
+        self._spans: collections.deque[Span] = collections.deque(maxlen=self.capacity)
+        self._totals: dict[str, list] = {}
+        self._counters: dict[str, dict] = {}
         self._lock = threading.Lock()
         self._ids = itertools.count(1)
         self._local = threading.local()
-        self._annotate = annotate
         self._clock = clock
 
     def _stack(self):
@@ -88,27 +111,41 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        """Time a ``with`` block as one span (nested spans get parented)."""
+        """Time a ``with`` block as one span (nested spans get parented);
+        yields the span's ``attrs`` dict, which the block may extend."""
         sid = next(self._ids)
         stack = self._stack()
-        parent = stack[-1] if stack else None
-        stack.append(sid)
-        ann = contextlib.nullcontext()
-        if self._annotate:
-            try:
-                import jax
-
-                ann = jax.profiler.TraceAnnotation(name)
-            except Exception:
-                pass
+        parent = stack[-1][0] if stack else None
+        stack.append((sid, attrs))
+        meta = {"span_id": sid} if parent is None else {"span_id": sid, "parent_id": parent}
         t0 = self._clock()
         try:
-            with ann:
-                yield sid
+            with TraceAnnotation(name, **meta):
+                yield attrs
         finally:
             t1 = self._clock()
             stack.pop()
             self._append(Span(name, sid, parent, t0, t1, _thread_name(), attrs))
+
+    def traced(self, name: str):
+        """Decorator: every call of the function is one ``name`` span."""
+
+        def wrap(fn):
+            @functools.wraps(fn)
+            def call(*args, **kwargs):
+                with self.span(name):
+                    return fn(*args, **kwargs)
+
+            return call
+
+        return wrap
+
+    def note(self, **attrs) -> None:
+        """Add ``attrs`` to the innermost span this thread has open (none
+        open: a no-op)."""
+        stack = self._stack()
+        if stack:
+            stack[-1][1].update(attrs)
 
     def record(
         self, name: str, t_start: float, t_end: float, parent_id=None, **attrs
@@ -117,29 +154,49 @@ class Tracer:
         sid = next(self._ids)
         if parent_id is None:
             stack = self._stack()
-            parent_id = stack[-1] if stack else None
+            parent_id = stack[-1][0] if stack else None
         self._append(Span(name, sid, parent_id, t_start, t_end, _thread_name(), attrs))
         return sid
+
+    def add(self, counter: str, site: str, value) -> None:
+        """Add ``value`` to ``counter`` at ``site`` (kept past the ring)."""
+        with self._lock:
+            sites = self._counters.setdefault(counter, {})
+            sites[site] = sites.get(site, 0) + value
 
     def _append(self, span: Span):
         with self._lock:
             self._spans.append(span)
+            total = self._totals.setdefault(span.name, [0, 0.0])
+            total[0] += 1
+            total[1] += span.t_end - span.t_start
 
     def spans(self) -> list[Span]:
-        """Snapshot of the collected spans (copy; safe to iterate)."""
+        """Snapshot of the spans the ring holds, oldest first (a copy)."""
         with self._lock:
             return list(self._spans)
 
+    def totals(self) -> dict:
+        """``{name: {"count", "seconds"}}`` over every span since ``clear``."""
+        with self._lock:
+            return {k: {"count": c, "seconds": s} for k, (c, s) in self._totals.items()}
+
+    def counters(self) -> dict:
+        """``{counter: {site: total}}`` over every ``add`` since ``clear``."""
+        with self._lock:
+            return {k: dict(v) for k, v in self._counters.items()}
+
     def clear(self):
-        """Drop every collected span (export first if you want them)."""
+        """Drop every span, total and counter (export first if you want them)."""
         with self._lock:
             self._spans.clear()
+            self._totals.clear()
+            self._counters.clear()
 
     def export(self, path=None) -> str:
-        """Serialize spans as JSON-lines; write to ``path`` when given.
-
-        Every line is one span dict with exactly :data:`SPAN_KEYS` keys.
-        Returns the serialized text either way.
+        """Serialize the ring's spans as JSON-lines; write to ``path`` when
+        given.  Every line is one span dict with exactly :data:`SPAN_KEYS`
+        keys.  Returns the serialized text either way.
         """
         buf = io.StringIO()
         for s in self.spans():
@@ -156,14 +213,16 @@ class NullTracer(Tracer):
     """Tracing disabled: same interface, every operation a no-op."""
 
     def __init__(self):
-        super().__init__()
-        self._null = contextlib.nullcontext(0)
+        super().__init__(capacity=0)
 
     def span(self, name, **attrs):  # noqa: D102 -- inherited contract
-        return self._null
+        return contextlib.nullcontext(attrs)
 
     def record(self, name, t_start, t_end, parent_id=None, **attrs):  # noqa: D102
         return 0
+
+    def add(self, counter, site, value):  # noqa: D102
+        pass
 
     def _append(self, span):
         pass
@@ -171,6 +230,10 @@ class NullTracer(Tracer):
 
 #: Shared do-nothing tracer -- the default collaborator of instrumented code.
 NULL_TRACER = NullTracer()
+
+#: The program's own tracer, always on: the entry points' ``prop.*`` spans
+#: and the ``h2d_bytes`` counter (docs/OBSERVABILITY.md has the table).
+PROGRAM = Tracer()
 
 
 def _thread_name() -> str:

@@ -7,8 +7,10 @@ while_loop carry without touching the bound dataflow.  The rest pins ring
 truncation semantics, host/device telemetry agreement, the span schema,
 the registry snapshot envelope, and the shared timing utilities.
 """
+import glob
 import json
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -23,6 +25,7 @@ from repro.obs import (
     SNAPSHOT_KEYS,
     SPAN_KEYS,
     MetricsRegistry,
+    PROGRAM,
     NullTracer,
     TelemetryPlane,
     Tracer,
@@ -30,6 +33,7 @@ from repro.obs import (
     host_snapshot,
     median_of,
     median_ratio,
+    default_registry,
     paired_trials,
     record_round,
     reset_rows,
@@ -334,10 +338,182 @@ def test_timing_utilities():
     assert len(trials) == 3 and all(len(row) == 2 for row in trials)
     assert median_ratio(trials) > 0.0
     assert median_of(trials, 0) > 0.0
-    tr = Tracer()
     phases = time_phases(
-        {"a": lambda: xs * 3.0, "b": lambda: xs * 4.0},
-        repeats=1, tracer=tr,
+        {"a": lambda: xs * 3.0, "b": lambda: xs * 4.0}, repeats=1
     )
     assert set(phases) == {"a", "b"} and all(v > 0.0 for v in phases.values())
-    assert {s.name for s in tr.spans()} == {"phase:a", "phase:b"}
+
+
+# -- the program tracer: entry-point spans, the ring, the profiler join ---
+
+
+def _children(spans, parent):
+    return {s.name: s for s in spans if s.parent_id == parent.span_id}
+
+
+def test_presolve_spans_nest_under_the_call():
+    p = make_set_cover(60, 30, seed=3)
+    PROGRAM.clear()
+    propagate_block_ell(p, use_pallas=False)
+    propagate_block_ell(
+        p, use_pallas=False, lb0=np.asarray(p.lb), ub0=np.asarray(p.ub)
+    )
+    spans = PROGRAM.spans()
+    calls = [s for s in spans if s.name == "prop.presolve"]
+    assert len(calls) == 2 and all(c.parent_id is None for c in calls)
+    for c in calls:
+        assert c.attrs == {"engine": "fused", "n_pad": 128}
+    first, second = (_children(spans, c) for c in calls)
+    # The first call prepares (tiles up) and builds its runner; the
+    # second hits both caches and uploads only its two bound vectors.
+    assert set(first) == {"prop.prepare", "prop.launch"}
+    assert first["prop.prepare"].attrs == {"hit": False}
+    assert first["prop.launch"].attrs == {"built": True}
+    up = [s for s in spans if s.parent_id == first["prop.prepare"].span_id]
+    assert [s.name for s in up] == ["prop.upload"]
+    assert up[0].attrs["site"] == "prepare" and up[0].attrs["bytes"] > 0
+    assert set(second) == {"prop.prepare", "prop.upload", "prop.launch"}
+    assert second["prop.prepare"].attrs == {"hit": True}
+    assert second["prop.launch"].attrs == {"built": False}
+    assert second["prop.upload"].attrs == {"site": "bounds", "bytes": 2 * p.n * 8}
+    for c in calls:
+        for child in _children(spans, c).values():
+            assert c.t_start <= child.t_start <= child.t_end <= c.t_end
+    h2d = PROGRAM.counters()["h2d_bytes"]
+    assert h2d["bounds"] == 2 * p.n * 8
+    assert h2d["prepare"] == up[0].attrs["bytes"]
+
+
+def test_node_spans_count_plane_bytes():
+    p = make_set_cover(40, 20, seed=0)
+    bsz, n_pad = 4, 128
+    lb = np.repeat(np.asarray(p.lb, np.float64)[None, :], bsz, axis=0)
+    ub = np.repeat(np.asarray(p.ub, np.float64)[None, :], bsz, axis=0)
+    PROGRAM.clear()
+    for _ in range(2):
+        propagate_nodes(p, lb, ub, use_pallas=False, dtype=np.float32)
+    spans = PROGRAM.spans()
+    calls = [s for s in spans if s.name == "prop.nodes"]
+    assert len(calls) == 2
+    plane_bytes = 2 * bsz * n_pad * 4
+    for c, built in zip(calls, (True, False)):
+        assert c.parent_id is None
+        assert c.attrs == {"nodes": bsz, "n_pad": n_pad}
+        kids = _children(spans, c)
+        assert set(kids) == {"prop.prepare", "prop.upload", "prop.launch"}
+        assert kids["prop.upload"].attrs == {"site": "nodes", "bytes": plane_bytes}
+        assert kids["prop.launch"].attrs == {"built": built}
+    assert PROGRAM.counters()["h2d_bytes"]["nodes"] == 2 * plane_bytes
+    assert PROGRAM.totals()["prop.nodes"]["count"] == 2
+
+
+def test_two_tier_call_nests_its_tiers():
+    p = make_knapsack(80, 20, seed=2)
+    PROGRAM.clear()
+    propagate_block_ell(p, policy=TierPolicy())
+    spans = PROGRAM.spans()
+    outer = [s for s in spans if s.name == "prop.presolve" and s.parent_id is None]
+    assert len(outer) == 1
+    tiers = [s for s in spans if s.name == "prop.presolve" and s.parent_id is not None]
+    assert len(tiers) == 2  # the float32 tier, then the endgame
+    assert all(t.parent_id == outer[0].span_id for t in tiers)
+
+
+@pytest.mark.f32native
+def test_ring_drops_oldest_while_totals_keep_counting():
+    tr = Tracer(capacity=4)
+    for i in range(10):
+        with tr.span("call", i=i) as attrs:
+            attrs["seen"] = True
+        tr.add("h2d_bytes", "nodes", 8)
+    assert [s.attrs["i"] for s in tr.spans()] == [6, 7, 8, 9]
+    assert all(s.attrs["seen"] for s in tr.spans())
+    assert tr.totals()["call"]["count"] == 10
+    assert tr.totals()["call"]["seconds"] >= 0.0
+    assert tr.counters() == {"h2d_bytes": {"nodes": 80}}
+    assert len(tr.export().splitlines()) == 4
+    tr.clear()
+    assert tr.spans() == [] and tr.totals() == {} and tr.counters() == {}
+
+
+@pytest.mark.f32native
+def test_traced_and_note():
+    tr = Tracer()
+
+    @tr.traced("outer")
+    def work(x):
+        """Doubles x."""
+        tr.note(x=x)
+        with tr.span("inner"):
+            tr.note(deep=True)
+        return 2 * x
+
+    assert work(3) == 6 and work.__doc__ == "Doubles x."
+    spans = {s.name: s for s in tr.spans()}
+    assert spans["outer"].attrs == {"x": 3}
+    assert spans["inner"].attrs == {"deep": True}
+    assert spans["inner"].parent_id == spans["outer"].span_id
+    tr.note(lost=True)  # no span open: nothing to add to
+    assert NullTracer().traced("x")(lambda: 1)() == 1
+
+
+def test_registry_reads_the_program_tracer():
+    p = make_set_cover(40, 20, seed=5)
+    propagate_block_ell(p, use_pallas=False)
+    snap = default_registry().snapshot()
+    assert snap["errors"] == {}
+    prog = snap["sources"]["program"]
+    assert prog["spans"]["prop.presolve"]["count"] >= 1
+    assert prog["counters"]["h2d_bytes"]["prepare"] > 0
+
+
+def _xplane_spans(trace_dir):
+    """``{span_id: (name, parent_id)}`` of the annotated host events."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                stats = dict(ev.stats)
+                if "span_id" in stats:
+                    out[int(stats["span_id"])] = (ev.name, stats.get("parent_id"))
+    return out
+
+
+def test_spans_join_the_profiler_trace_on_span_id(tmp_path):
+    p = make_set_cover(60, 30, seed=4)
+    lb = np.repeat(np.asarray(p.lb, np.float64)[None, :], 2, axis=0)
+    ub = np.repeat(np.asarray(p.ub, np.float64)[None, :], 2, axis=0)
+    propagate_block_ell(p, use_pallas=False)  # compile outside the trace
+    propagate_nodes(p, lb, ub, use_pallas=False)
+    PROGRAM.clear()
+    with jax.profiler.trace(str(tmp_path)):
+        jax.block_until_ready(propagate_block_ell(p, use_pallas=False).lb)
+        jax.block_until_ready(propagate_nodes(p, lb, ub, use_pallas=False).lb)
+    traced = _xplane_spans(tmp_path)
+    spans = PROGRAM.spans()
+    assert {s.name for s in spans} >= {
+        "prop.presolve", "prop.nodes", "prop.prepare", "prop.upload", "prop.launch",
+    }
+    for s in spans:
+        name, parent = traced[s.span_id]
+        assert name == s.name
+        assert parent == s.parent_id
+
+
+def test_bounds_bitwise_with_the_profiler_on(tmp_path):
+    p = make_knapsack(120, 30, seed=6)
+    lb = np.repeat(np.asarray(p.lb, np.float64)[None, :], 3, axis=0)
+    ub = np.repeat(np.asarray(p.ub, np.float64)[None, :], 3, axis=0)
+    ub[1, :5] = 0.0
+    off = propagate_block_ell(p, use_pallas=False)
+    off_n = propagate_nodes(p, lb, ub, use_pallas=False)
+    with jax.profiler.trace(str(tmp_path)):
+        on = propagate_block_ell(p, use_pallas=False)
+        on_n = propagate_nodes(p, lb, ub, use_pallas=False)
+        jax.block_until_ready((on.lb, on_n.lb))
+    assert_same_bounds(off, on)
+    for a, b in ((off_n.lb, on_n.lb), (off_n.ub, on_n.ub), (off_n.rounds, on_n.rounds)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))

@@ -12,6 +12,7 @@ every test worker collects the same tests and only the worker that runs
 this file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -47,12 +48,24 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
+def _kernel_calls(text):
+    """Base names (``%name.N`` less ``%`` and ``.N``) of the compiled
+    program's Mosaic custom calls."""
+    return [
+        re.match(r"\s*%?([\w.-]+?)(\.\d+)? = ", line).group(1)
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+
+
 def _compile(sharding, fn, *shapes):
-    """Lower + compile ``fn`` for the described chip; returns the text."""
+    """Lower + compile ``fn`` for the described chip; returns the text.
+    Every Mosaic kernel in it carries its name from ``KERNEL_NAMES``."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
+    calls = _kernel_calls(text)
+    assert calls and set(calls) <= set(kern.KERNEL_NAMES.values()), calls
     return text
 
 
@@ -168,6 +181,57 @@ def test_node_objective_compiles(one_chip):
 
     _compile(one_chip, fn, ((B, n_pad), F32), ((B, n_pad), F32),
              ((n_pad,), F32), ((n_pad,), jnp.bool_), ((n_pad,), jnp.bool_))
+
+
+def _presolve_runner(p, scatter):
+    """The cached, jitted fixed point ``propagate_block_ell`` builds for
+    ``p`` with Mosaic kernels at float32 (running it needs the chip)."""
+    prep = ops.prepare_block_ell(p, dtype=F32)
+    try:
+        ops.propagate_block_ell(
+            p, dtype=F32, interpret=False, donate=True, scatter=scatter
+        )
+    except Exception:  # Mosaic kernels do not run on the CPU
+        pass
+    return next(
+        runner for key, (_, runner) in ops._runner_cache._d.items()
+        if key[0] == id(prep.d.val) and key[4] == scatter
+    ), prep.n_pad
+
+
+@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
+@pytest.mark.parametrize("scatter", ["fused", "partitioned"])
+def test_presolve_fixed_point_is_named(one_chip, scatter):
+    """The compiled fixed point is the module ``jit_fixed_point_presolve``
+    and each kernel in its while body keeps its own name (no ``%body.N``
+    custom call)."""
+    from repro.data import make_mixed
+
+    runner, n_pad = _presolve_runner(make_mixed(m=200, n=300, seed=3), scatter)
+    args = [jax.ShapeDtypeStruct((n_pad,), F32, sharding=one_chip)] * 2
+    text = runner.lower(*args).compile().as_text()
+    assert text.startswith("HloModule jit_fixed_point_presolve")
+    calls = _kernel_calls(text)
+    assert calls and set(calls) <= set(kern.KERNEL_NAMES.values()), calls
+    assert "%body." not in "\n".join(
+        ln for ln in text.splitlines() if "tpu_custom_call" in ln
+    )
+
+
+def test_node_fixed_point_is_named(one_chip):
+    """The node runner compiles as ``jit_fixed_point_nodes`` with the
+    node kernel under its own name."""
+    from repro.data import make_set_cover
+
+    p = make_set_cover(200, 150, seed=0)
+    prep = ops.prepare_block_ell(p, dtype=F32)
+    assert prep.fits_one_chunk  # the Pallas node kernel, not the XLA round
+    runner = ops.node_batch_runner(prep, B, interpret=False, donate=True)
+    args = [jax.ShapeDtypeStruct((B, prep.n_pad), F32, sharding=one_chip)] * 2
+    text = runner.lower(*args).compile().as_text()
+    assert text.startswith("HloModule jit_fixed_point_nodes")
+    assert kern.KERNEL_NAMES["node_fused_scatter"] in _kernel_calls(text)
+    assert set(_kernel_calls(text)) <= set(kern.KERNEL_NAMES.values())
 
 
 def test_float64_pallas_compile_raises():
