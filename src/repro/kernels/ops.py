@@ -16,7 +16,10 @@ Engine anatomy (see README "fused-scatter dataflow"):
     reduction into ``(2, n_pad)`` accumulators that stay in VMEM across all
     grid steps; a small merge kernel then folds them into (lb, ub) in place
     (``input_output_aliases``).  NO nnz-shaped tensor -- neither gathered
-    bounds nor candidates -- is produced in HBM during a round.
+    bounds nor candidates -- is produced in HBM during a round.  Where it
+    halves the chunk rows, short rows are packed into shared chunk rows
+    first (:class:`PackedStream`): the kernel's gather and scatter cost
+    the same per chunk row however few of its slots are used.
   * ``scatter="partitioned"`` -- the column-slab engine for instances whose
     ``n_pad`` exceeds the VMEM accumulator budget: the padded column space
     is split into balanced slabs (``default_slab_width``, capped at
@@ -510,6 +513,177 @@ def build_slab_partition(
 
 
 # ---------------------------------------------------------------------------
+# Packed tile stream: short rows share chunk rows (the fused engine's input)
+# ---------------------------------------------------------------------------
+
+
+class PackedStream(NamedTuple):
+    """The fused engine's tile stream with short rows packed together.
+
+    Block-ELL gives every row whole ``K``-slot chunk rows, and the fused
+    round's in-kernel gather and scatter cost the same per chunk row
+    however few slots hold a nonzero.  Here each row of at most ``K``
+    nonzeros that repeats no column is packed WHOLE into a shared chunk
+    row, first fit over :data:`PACK_OPEN_ROWS` open chunk rows, as a
+    contiguous segment of slots; the rows of one chunk row never share a
+    column, so the one-hot scatter stays exact on every packed tile.
+    Every slot carries its row's local segment id (``seg``, -1 on
+    padding) and sides.  Empty rows take no slot.
+
+    The other rows (longer than ``K``, or repeating a column) keep their
+    block-ELL chunks as the LONG sub-stream ``l_*``, with rows renumbered
+    ``0 .. n_long - 1`` (``n_long`` on padding chunks), for the split
+    pair of kernels.  Built once per prepared instance
+    (``PreparedBlockEll.packed_stream``)."""
+
+    val: jnp.ndarray      # (Tp, R, K) packed short rows, 0 == padding
+    col: jnp.ndarray      # (Tp, R, K) columns (the prep's index dtype)
+    ii_g: jnp.ndarray     # (Tp, R, K) is_int at each slot
+    seg: jnp.ndarray      # (Tp, R, K) segment within the chunk row, -1 padding
+    lhs_s: jnp.ndarray    # (Tp, R, K) the slot's row sides
+    rhs_s: jnp.ndarray    # (Tp, R, K)
+    l_val: jnp.ndarray    # (Tl, R, K) chunks of the long rows
+    l_col: jnp.ndarray    # (Tl, R, K)
+    l_ii: jnp.ndarray     # (Tl, R, K)
+    l_row: jnp.ndarray    # (Tl, R) int32 long-row id, n_long == padding
+    l_lhs: jnp.ndarray    # (Tl, R)
+    l_rhs: jnp.ndarray    # (Tl, R)
+    n_long: int           # rows in the long sub-stream
+    long_chunks: int      # chunks of the long rows (before tile fill)
+
+    @property
+    def slots(self) -> int:
+        """Slots a round runs over: both streams' tiles x R x K."""
+        return int(self.val.size + self.l_val.size)
+
+
+#: Chunk rows the packer keeps open for first fit: a row that fits none
+#: opens a new one and the oldest closes.
+PACK_OPEN_ROWS = 4
+
+
+def _first_fit_rows(starts, lengths, cols, k, open_rows=PACK_OPEN_ROWS):
+    """Place rows (columns ``cols[starts[i] : starts[i] + lengths[i]]``)
+    whole into ``k``-slot chunk rows, first fit over ``open_rows`` open
+    ones, never two rows that share a column in one chunk row.  Returns
+    per row its chunk row, first slot and segment (chunk row -1 for a row
+    that repeats a column, which is not placed) and the chunk rows used."""
+    cols = cols.tolist()
+    bins, offs, segs = [], [], []
+    open_ = []  # [chunk row, slots used, rows placed, columns used]
+    used = 0
+    for a, n in zip(starts.tolist(), lengths.tolist()):
+        c = set(cols[a:a + n])
+        if len(c) != n:
+            bins.append(-1)
+            offs.append(0)
+            segs.append(-1)
+            continue
+        for b in open_:
+            if b[1] + n <= k and b[3].isdisjoint(c):
+                break
+        else:
+            b = [used, 0, 0, set()]
+            used += 1
+            open_.append(b)
+            if len(open_) > open_rows:
+                del open_[0]
+        bins.append(b[0])
+        offs.append(b[1])
+        segs.append(b[2])
+        b[1] += n
+        b[2] += 1
+        b[3] |= c
+        if b[1] == k:
+            open_.remove(b)
+    as_ = lambda x: np.asarray(x, np.int64)
+    return as_(bins), as_(offs), as_(segs), used
+
+
+def build_packed_stream(val, col, chunk_row, lhs1, rhs1, is_int, ii_dtype):
+    """Pack a single instance's ``(T, R, K)`` block-ELL stream (host
+    arrays; ``lhs1``/``rhs1`` the ``(m + 1,)`` padded sides) into a
+    :class:`PackedStream`, or ``None`` where packing does not pay: the
+    packed and long chunk rows together must be at most half of the
+    block-ELL stream's row-holding chunks (instances whose rows are mostly
+    long keep their tiles)."""
+    val = np.asarray(val)
+    t, r, k = val.shape
+    m = len(lhs1) - 1
+    cval, ccol, crow, _, _ = chunk_stream(val, col, chunk_row)
+    held = crow < m
+    if not held.any():
+        return None
+    nz = cval != 0
+    cnt = nz.sum(axis=1)
+    chunks = np.bincount(crow[held], minlength=m)
+    short = held & (chunks[np.minimum(crow, m - 1)] == 1) & (cnt > 0)
+    sc = np.flatnonzero(short)
+    s_nz = nz[sc]
+    s_len = cnt[sc]
+    s_starts = np.concatenate([[0], np.cumsum(s_len)[:-1]]).astype(np.int64)
+    s_col = ccol[sc][s_nz].astype(np.int64)
+    bins, offs, segs, used = _first_fit_rows(s_starts, s_len, s_col, k)
+    placed = bins >= 0
+    long_ = held & (cnt > 0) & ~short
+    long_[sc[~placed]] = True
+    lc = np.flatnonzero(long_)
+    if used + lc.size > held.sum() / 2:
+        return None
+
+    # Packed stream: every placed row's nonzeros at consecutive slots.
+    tp = -(-used // r)
+    size = tp * r * k
+    p_len = np.where(placed, s_len, 0)
+    first = np.repeat(bins * k + offs, p_len)
+    at = np.arange(p_len.sum()) - np.repeat(np.cumsum(p_len) - p_len, p_len)
+    slot = first + at
+    take = np.repeat(placed, s_len)
+    p_col = s_col[take]
+    # The one-hot scatter is exact only where no chunk row holds a column
+    # twice; a packed tile must never need the compare-based fallback.
+    key = (slot // k) * (int(p_col.max(initial=0)) + 1) + p_col
+    assert np.unique(key).size == key.size, "packed rows share a column"
+    rows = np.repeat(crow[sc], p_len)
+    lay = lambda fill, dt, x: _scatter_slots(size, fill, dt, slot, x).reshape(tp, r, k)
+    packed = (
+        lay(0, val.dtype, cval[sc][s_nz][take]),
+        lay(0, np.asarray(col).dtype, p_col),
+        lay(0, ii_dtype, np.asarray(is_int)[p_col]),
+        lay(-1, np.int8 if np.dtype(ii_dtype) == np.int8 and k <= 128 else np.int32,
+            np.repeat(segs, p_len)),
+        lay(0, val.dtype, lhs1[rows]),
+        lay(0, val.dtype, rhs1[rows]),
+    )
+
+    # Long sub-stream: the remaining rows' own chunks, rows renumbered.
+    long_rows, l_ids = np.unique(crow[lc], return_inverse=True)
+    tl = -(-lc.size // r)
+    fill = tl * r - lc.size
+    padc = lambda x, v: np.concatenate(
+        [x, np.full((fill,) + x.shape[1:], v, x.dtype)]
+    ).reshape((tl, r) + x.shape[1:])
+    l_val = padc(cval[lc], 0)
+    l_col = padc(ccol[lc], 0)
+    l_row = padc(l_ids.astype(np.int32), long_rows.size)
+    l_src = padc(crow[lc], m)
+    longs = (
+        l_val, l_col, np.asarray(is_int)[l_col].astype(ii_dtype), l_row,
+        lhs1[l_src], rhs1[l_src],
+    )
+    arrays = _upload("prepare", *((x, None) for x in packed + longs))
+    return PackedStream(
+        *arrays, n_long=int(long_rows.size), long_chunks=int(lc.size),
+    )
+
+
+def _scatter_slots(size, fill, dtype, slot, x):
+    out = np.full(size, fill, dtype)
+    out[slot] = x
+    return out
+
+
+# ---------------------------------------------------------------------------
 # Prepared instances: one-time setup, hoisted round constants, LRU-cached
 # ---------------------------------------------------------------------------
 
@@ -632,6 +806,10 @@ class PreparedBlockEll:
     _slabs: dict = dataclasses.field(
         default_factory=dict, compare=False, repr=False
     )
+    # The packed stream (or None where it does not pay), built lazily.
+    _packed: dict = dataclasses.field(
+        default_factory=dict, compare=False, repr=False
+    )
 
     def slab_partition(self, slab: int | None = None) -> SlabPartition:
         """This instance's tile stream re-bucketed into ``slab``-wide column
@@ -661,6 +839,20 @@ class PreparedBlockEll:
             )
             self._slabs[s] = part
         return part
+
+    def packed_stream(self) -> PackedStream | None:
+        """This instance's short rows packed into shared chunk rows, for
+        the fused engine (:class:`PackedStream`), or ``None`` where packing
+        does not pay.  Built once from the resident tiles (a host-side pass)
+        and cached on the prep, like :meth:`slab_partition`."""
+        if "stream" not in self._packed:
+            d = self.d
+            self._packed["stream"] = build_packed_stream(
+                np.asarray(d.val), np.asarray(d.col), np.asarray(d.chunk_row),
+                np.asarray(d.lhs1), np.asarray(d.rhs1), np.asarray(d.is_int),
+                self.ii_g.dtype,
+            )
+        return self._packed["stream"]
 
     def pad_bound(self, arr):
         """One caller bound vector -> the column-padded ``(n_pad,)`` domain
@@ -702,7 +894,8 @@ _prep_cache = LRU(maxsize=32, on_evict=_drop_runners_for)
 
 @PROGRAM.traced("prop.prepare")
 def prepare_block_ell(
-    p: Problem, tile_rows: int = 8, tile_width: int = 128, dtype=None
+    p: Problem, tile_rows: int = 8, tile_width: int = 128, dtype=None,
+    *, pack: bool = False,
 ) -> PreparedBlockEll:
     """One-time setup for kernel-backed propagation, LRU-cached per matrix
     STRUCTURE (``csr``/``lhs``/``rhs``/``is_int`` identity -- maxsize 32,
@@ -716,10 +909,19 @@ def prepare_block_ell(
     live; a hit from a problem whose bounds differ from the cached defaults
     returns a cheap bounds-swapped view sharing every device tile.
     ``dtype=None`` is the instance's own dtype, float32 on a TPU
-    (:func:`~repro.core.types.resolve_dtype`).  Each call is one
+    (:func:`~repro.core.types.resolve_dtype`).  ``pack=True`` also builds
+    (once per prep) the packed stream of the fused engine
+    (:meth:`PreparedBlockEll.packed_stream`).  Each call is one
     ``prop.prepare`` span of the program tracer, ``hit`` telling a cache
-    hit from a build.
+    hit from a build and ``packed`` whether the prep carries a packed
+    stream for this call.
     """
+    prep = _prepare_cached(p, tile_rows, tile_width, dtype)
+    PROGRAM.note(packed=pack and prep.packed_stream() is not None)
+    return prep
+
+
+def _prepare_cached(p: Problem, tile_rows: int, tile_width: int, dtype) -> PreparedBlockEll:
     dt = resolve_dtype(dtype, p.csr.val.dtype)
     anchors = _structure_anchors(p)
     key = tuple(id(a) for a in anchors) + (tile_rows, tile_width, dt.str)
@@ -840,13 +1042,40 @@ def block_ell_round(
     return bnd.apply_updates(lb, ub, best_l, best_u, eps, inf, outward)
 
 
-def _combine_chunk_partials(prep: PreparedBlockEll, mf, mc, xf, xc):
-    """Chunk partials -> completed per-chunk row aggregates (long rows)."""
-    d = prep.d
-    crow = d.chunk_row.reshape(-1)
-    seg = lambda x: jax.ops.segment_sum(x.reshape(-1), crow, num_segments=prep.m + 1)
-    g = lambda x: seg(x)[d.chunk_row]
+def _combine_chunk_partials(chunk_row, rows: int, mf, mc, xf, xc):
+    """Chunk partials -> completed per-chunk row aggregates (long rows);
+    ``chunk_row`` numbers the chunks' rows ``0 .. rows`` (``rows`` the
+    padding)."""
+    crow = chunk_row.reshape(-1)
+    seg = lambda x: jax.ops.segment_sum(x.reshape(-1), crow, num_segments=rows + 1)
+    g = lambda x: seg(x)[chunk_row]
     return g(mf), g(mc), g(xf), g(xc)
+
+
+def _packed_best_bounds(packed: PackedStream, n_pad: int, lb, ub, *, int_eps, inf, interpret):
+    """The fused engine's column-wise best bounds over a packed stream:
+    the packed kernel over the packed rows, the split pair over the long
+    rows' own chunks, combined by max/min."""
+    best = []
+    if packed.val.shape[0]:
+        best.append(kern.packed_round_tiles(
+            packed.val, packed.col, packed.ii_g, packed.seg, packed.lhs_s,
+            packed.rhs_s, lb, ub, n_pad, int_eps, inf, interpret,
+        ))
+    if packed.l_val.shape[0]:
+        parts = kern.activities_gather_tiles(
+            packed.l_val, packed.l_col, lb, ub, n_pad, inf, interpret
+        )
+        aggs = _combine_chunk_partials(packed.l_row, packed.n_long, *parts)
+        best.append(kern.candidates_scatter_tiles(
+            packed.l_val, packed.l_col, packed.l_ii, *aggs, packed.l_lhs,
+            packed.l_rhs, lb, ub, n_pad, int_eps, inf, interpret,
+        ))
+    if not best:
+        return jnp.full_like(lb, -inf), jnp.full_like(ub, inf)
+    return functools.reduce(
+        lambda a, b: (jnp.maximum(a[0], b[0]), jnp.minimum(a[1], b[1])), best
+    )
 
 
 def _straddle_aggregates(part: SlabPartition, lb, ub, active, *, node, inf, interpret):
@@ -952,9 +1181,12 @@ def _prepared_round(
     interpret: bool | None,
     slab: int | None = None,
     outward: float = 0.0,
+    packed: PackedStream | None = None,
 ):
     """One round over hoisted constants.  (lb, ub) live in the column-padded
-    ``(n_pad,)`` domain end to end; only the bound gathers run in XLA."""
+    ``(n_pad,)`` domain end to end; only the bound gathers run in XLA.
+    ``packed`` (Pallas, ``scatter="fused"``) runs the fused round over the
+    packed stream instead of the block-ELL tiles."""
     d = prep.d
     if use_pallas:
         interpret = kern.resolve_interpret(interpret, lb.dtype)
@@ -982,7 +1214,12 @@ def _prepared_round(
         )
 
     if scatter == "fused":
-        if fused:
+        if packed is not None:
+            best_l, best_u = _packed_best_bounds(
+                packed, prep.n_pad, lb, ub, int_eps=int_eps, inf=inf,
+                interpret=interpret,
+            )
+        elif fused:
             # Fully fused: even the bound gather happens in the kernel, so
             # no nnz-shaped tensor is produced in HBM at all this round.
             if use_pallas:
@@ -1006,7 +1243,9 @@ def _prepared_round(
                 mf, mc, xf, xc = kref.activities_gather_tiles_ref(
                     d.val, d.col, lb, ub, prep.n_pad, inf
                 )
-            rmf, rmc, rxf, rxc = _combine_chunk_partials(prep, mf, mc, xf, xc)
+            rmf, rmc, rxf, rxc = _combine_chunk_partials(
+                d.chunk_row, prep.m, mf, mc, xf, xc
+            )
             if use_pallas:
                 best_l, best_u = kern.candidates_scatter_tiles(
                     d.val, d.col, prep.ii_g, rmf, rmc, rxf, rxc,
@@ -1042,7 +1281,9 @@ def _prepared_round(
             mf, mc, xf, xc = kern.activities_tiles(d.val, lb_g, ub_g, inf, interpret)
         else:
             mf, mc, xf, xc = kref.activities_tiles_ref(d.val, lb_g, ub_g, inf)
-        rmf, rmc, rxf, rxc = _combine_chunk_partials(prep, mf, mc, xf, xc)
+        rmf, rmc, rxf, rxc = _combine_chunk_partials(
+            d.chunk_row, prep.m, mf, mc, xf, xc
+        )
         if use_pallas:
             lcand, ucand = kern.candidates_tiles(
                 d.val, lb_g, ub_g, prep.ii_g, rmf, rmc, rxf, rxc,
@@ -1096,9 +1337,13 @@ def round_fn_for(
     """A jit-able ``(lb, ub) -> (lb, ub, changed)`` round closure over a
     prepared instance (bounds in the ``(n_pad,)`` domain).  ``slab``
     overrides the partitioned engine's column-slab width (default
-    :data:`SLAB_NPAD`; ignored by the other scatter modes)."""
+    :data:`SLAB_NPAD`; ignored by the other scatter modes).  ``fused=None``
+    lets the instance choose: with Pallas and the fused engine, the packed
+    stream where it pays (:meth:`PreparedBlockEll.packed_stream`), else
+    the single kernel when every row fits one chunk."""
     scatter = _resolve_scatter(scatter, prep)
     do_fuse = prep.fits_one_chunk if fused is None else bool(fused)
+    packed = _packed_for(prep, use_pallas, scatter, fused is None)
     eps = cfg.eps_for(prep.d.val.dtype)
     return functools.partial(
         _prepared_round,
@@ -1112,7 +1357,18 @@ def round_fn_for(
         interpret=interpret,
         slab=slab,
         outward=cfg.outward_for(prep.d.val.dtype),
+        packed=packed,
     )
+
+
+def _packs(use_pallas: bool, scatter: str, auto: bool) -> bool:
+    """The packed stream serves the Pallas fused engine when the caller
+    leaves the tile layout to the instance (``fused`` auto)."""
+    return use_pallas and scatter == "fused" and auto
+
+
+def _packed_for(prep: PreparedBlockEll, use_pallas, scatter, auto):
+    return prep.packed_stream() if _packs(use_pallas, scatter, auto) else None
 
 
 # ---------------------------------------------------------------------------
@@ -1145,11 +1401,26 @@ def _resolve_scatter(scatter: str, prep: PreparedBlockEll) -> str:
     (overridable via :data:`AUTO_LARGE_SCATTER_ENV`), so the fused
     ~16 B/nnz dataflow holds at every instance size; ``segment`` (the
     materializing oracle) is otherwise only ever explicit."""
+    return _scatter_for(scatter, prep.n_pad)
+
+
+def _scatter_for(scatter: str, n_pad: int) -> str:
     if scatter == "auto":
-        return "fused" if prep.n_pad <= SCATTER_MAX_NPAD else _auto_large_scatter()
+        return "fused" if n_pad <= SCATTER_MAX_NPAD else _auto_large_scatter()
     if scatter not in ("fused", "segment", "partitioned"):
         raise ValueError(f"unknown scatter mode: {scatter!r}")
     return scatter
+
+
+def _round_slots(prep: PreparedBlockEll, scatter: str, packed, slab) -> int:
+    """Slots a round runs over: the packed stream's, the slab copies'
+    (main and straddle streams), or the block-ELL tiles'."""
+    if packed is not None:
+        return packed.slots
+    if scatter == "partitioned":
+        part = prep.slab_partition(slab)
+        return int(part.val.size + part.a_val.size)
+    return int(prep.d.val.size)
 
 
 # Jitted single-instance fixed points, cached per matrix structure + config:
@@ -1195,9 +1466,11 @@ def propagate_block_ell(
 ) -> PropagationResult:
     """Kernel-backed propagation.
 
-    ``fused='auto'`` picks the Alg.-3 fusion whenever every row fits in one
-    chunk (the paper's common case).  ``scatter='auto'`` picks the fully
-    fused in-VMEM column reduction while the padded column count fits the
+    ``fused='auto'`` lets the instance pick its tiles: with Pallas and the
+    fused engine, the packed stream wherever it halves the chunk rows
+    (short rows share chunk rows, :class:`PackedStream`), else the Alg.-3
+    fusion whenever every row fits in one chunk (the paper's common case).
+    ``scatter='auto'`` picks the fully fused in-VMEM column reduction while the padded column count fits the
     accumulator budget and the column-slab ``partitioned`` engine beyond it
     (``slab`` overrides its window width); ``scatter='segment'`` forces the
     materializing oracle.  ``donate=None`` donates the bound buffers
@@ -1225,8 +1498,9 @@ def propagate_block_ell(
     cache key (on/off are distinct compiled runners, each cached once).
 
     Each call is one ``prop.presolve`` span of the program tracer
-    (``obs.trace.PROGRAM``, attrs ``engine`` and ``n_pad``), from entry to
-    return: the launch is asynchronous and nothing waits for the device.
+    (``obs.trace.PROGRAM``, attrs ``engine``, ``n_pad``, and ``slots`` and
+    ``nnz``: the slots of the tile streams a round runs and the nonzeros
+    they hold), from entry to return: the launch is asynchronous and nothing waits for the device.
     Inside it are the ``prop.prepare``, ``prop.upload`` and ``prop.launch``
     spans (``built`` on a runner-cache miss); a two-tier call nests one
     ``prop.presolve`` span per tier."""
@@ -1281,25 +1555,29 @@ def propagate_block_ell(
     if policy is not None:
         stop_progress = policy.stop_progress
         patience = policy.patience
-    prep = prepare_block_ell(p, tile_rows, tile_width, dtype)
-    do_fuse = (
-        prep.fits_one_chunk if fused == "auto" else bool(fused == "yes" or fused is True)
+    scatter = _scatter_for(scatter, kern.col_pad(p.n))
+    auto = fused == "auto"
+    pack = _packs(use_pallas, scatter, auto)
+    prep = prepare_block_ell(p, tile_rows, tile_width, dtype, pack=pack)
+    do_fuse = prep.fits_one_chunk if auto else bool(fused == "yes" or fused is True)
+    packed = _packed_for(prep, use_pallas, scatter, auto)
+    PROGRAM.note(
+        engine=scatter, n_pad=prep.n_pad, slots=_round_slots(prep, scatter, packed, slab),
+        nnz=int(p.nnz),
     )
-    scatter = _resolve_scatter(scatter, prep)
-    PROGRAM.note(engine=scatter, n_pad=prep.n_pad)
     do_donate = donate_supported() if donate is None else bool(donate)
     n = prep.n
 
     key = (
         id(prep.d.val), cfg, use_pallas, do_fuse, scatter, interpret, do_donate,
-        driver, slab, stop_progress, patience, tel_cap,
+        driver, slab, stop_progress, patience, tel_cap, packed is not None,
     )
     anchors = (prep.d.val,)
 
     def build():
         donate_kw = {"donate_argnums": (0, 1)} if do_donate else {}
         round_fn = round_fn_for(
-            prep, cfg, use_pallas, scatter, do_fuse, interpret, slab
+            prep, cfg, use_pallas, scatter, None if auto else do_fuse, interpret, slab
         )
         if driver == "host_loop":
             # Progress is computed INSIDE the jit, where the pre-round

@@ -42,6 +42,12 @@ Fully fused scatter kernels (the zero-HBM-tensor round engine):
   * ``_candidates_scatter_kernel`` -- same fused gather+scatter, but
         candidates are computed from completed row aggregates gathered per
         chunk (rows that span several chunks; the CSR-vector analogue).
+  * ``_packed_round_kernel``       -- kernel D over PACKED tiles: each
+        chunk row holds several whole short rows as contiguous segments of
+        slots (column-disjoint within the chunk row), so a chunk row's
+        gather and scatter serve all its rows; per-slot row aggregates come
+        from a segmented reduction within the chunk row (two one-hot
+        products on the MXU, see ``_segment_totals``).
   * ``_apply_updates_kernel``      -- the small merge kernel: folds the
         accumulated best bounds into (lb, ub) with the shared
         ``bounds.apply_updates`` semantics.  ``input_output_aliases`` donates
@@ -127,6 +133,7 @@ KERNEL_NAMES = {
     "fused_round": "prop_fused_round",
     "fused_scatter": "prop_fused_scatter",
     "candidates_scatter": "prop_candidates_scatter",
+    "packed_round": "prop_packed_round",
     "apply_updates": "prop_apply_updates",
     "batched_fused_scatter": "prop_batched_fused_scatter",
     "node_fused_scatter": "prop_node_fused_scatter",
@@ -221,6 +228,12 @@ def tile_contributions(val, lb_g, ub_g, inf):
     return pos, pad, min_is_inf, max_is_inf, c_min, c_max
 
 
+def _per_slot(x, val):
+    """Per-row data ``(.., R)`` broadcast over the ``K`` slots; data that is
+    already per slot (the packed stream's) passes as it is."""
+    return x if x.ndim == val.ndim else x[..., None]
+
+
 def tile_candidates(
     val,
     lb_g,
@@ -237,7 +250,9 @@ def tile_candidates(
 ):
     """Residual activities (§3.4 single-infinity rule) + bound candidates
     (Eqs. 4/5) + integrality rounding.  Row aggregates / sides are (.., R)
-    and broadcast over the K axis.  Pure jnp: callable inside kernels.
+    and broadcast over the K axis, or (.., R, K) per slot (packed tiles,
+    where a chunk row holds several rows).  Pure jnp: callable inside
+    kernels.
 
     Candidates use the division-first form ``(side - row_sum) / a + bound``
     rather than dividing the residual ``row_sum - a * bound``: the two are
@@ -250,12 +265,10 @@ def tile_candidates(
     pos, pad, min_is_inf, max_is_inf, _, _ = tile_contributions(
         val, lb_g, ub_g, inf
     )
-    rmf = row_min_fin[..., None]
-    rmc = row_min_cnt[..., None]
-    rxf = row_max_fin[..., None]
-    rxc = row_max_cnt[..., None]
-    lhs_b = lhs[..., None]
-    rhs_b = rhs[..., None]
+    rmf, rmc, rxf, rxc, lhs_b, rhs_b = (
+        _per_slot(x, val)
+        for x in (row_min_fin, row_min_cnt, row_max_fin, row_max_cnt, lhs, rhs)
+    )
 
     # Residual usable at this entry (§3.4): all contributions finite and
     # the row sum complete (cnt == 0), or exactly this entry's bound
@@ -829,6 +842,120 @@ def candidates_scatter_tiles(
     rows = (row_min_fin, row_min_cnt, row_max_fin, row_max_cnt, lhs_g, rhs_g)
     best_l, best_u = fn(
         val, col, _int_operand(is_int_g), *(_rows3(x) for x in rows),
+        _lane_rows(lb, block), _lane_rows(ub, block),
+    )
+    return best_l.reshape(n_pad), best_u.reshape(n_pad)
+
+
+# ---------------------------------------------------------------------------
+# Kernel P: the packed round -- several short rows share one chunk row
+# ---------------------------------------------------------------------------
+
+
+def _segment_totals(seg, c_min, c_max, min_inf, max_inf):
+    """Per-slot row aggregates of one packed ``(1, K)`` chunk row: every
+    slot gets the sums over the slots of its own segment (its row).
+
+    Two one-hot products on the MXU with the segment one-hot ``[s, k] =
+    seg[k] == s``: the first sums each segment's terms (the float32
+    contributions as bfloat16 pieces, the infinity flags as 0/1), the
+    second hands each segment's totals back to its slots.  The flags sum
+    exactly and the hand-back takes one term per slot, so counts are
+    exact and each finite sum is a float32 sum of the row's own terms in
+    another order.  Slots of no segment (``seg < 0``, padding) read 0."""
+    k = seg.shape[-1]
+    p_min, acc = _pieces(c_min)
+    p_max, _ = _pieces(c_max)
+    dt = p_min[0].dtype
+    onehot = _lane_onehot(seg, k, dt)
+    flag = lambda b: jnp.where(b, _ONE, _ZERO).astype(dt)
+    terms = jnp.concatenate([*p_min, *p_max, flag(min_inf), flag(max_inf)], axis=0)
+    tot = _onehot_dot([terms], onehot, 1, acc)
+    n = len(p_min)
+    # The pieces of one value, summed hi + mid + lo in rows at..at+n-1.
+    value = lambda x, at: sum((x[i:i + 1] for i in range(at + 1, at + n)), x[at:at + 1])
+    q_min, _ = _pieces(value(tot, 0).astype(c_min.dtype))
+    q_max, _ = _pieces(value(tot, n).astype(c_min.dtype))
+    back = jnp.concatenate([*q_min, *q_max, tot[2 * n:].astype(dt)], axis=0)
+    per = _onehot_dot([back], onehot, 0, acc)
+    mf, xf = (value(per, at).astype(c_min.dtype) for at in (0, n))
+    mc, xc = (per[i:i + 1].astype(jnp.int32) for i in (2 * n, 2 * n + 1))
+    return mf, mc, xf, xc
+
+
+def tile_segment_aggregates(val, lb_g, ub_g, seg, inf):
+    """Per-slot row aggregates ``(mf, mc, xf, xc)`` of a packed ``(R, K)``
+    tile, each ``(R, K)``: slot ``k`` of chunk row ``i`` holds the
+    aggregates of the row whose segment ``seg[i, k]`` it belongs to."""
+    _, _, min_inf, max_inf, c_min, c_max = tile_contributions(val, lb_g, ub_g, inf)
+    rows = [
+        _segment_totals(seg[i:i + 1], c_min[i:i + 1], c_max[i:i + 1],
+                        min_inf[i:i + 1], max_inf[i:i + 1])
+        for i in range(val.shape[0])
+    ]
+    return tuple(jnp.concatenate(parts, axis=0) for parts in zip(*rows))
+
+
+def _packed_round_kernel(
+    val_ref, col_ref, ii_ref, seg_ref, lhs_ref, rhs_ref, lb_ref, ub_ref,
+    bl_ref, bu_ref, *, int_eps, inf, block,
+):
+    """Kernel P: the whole round of a packed tile, whose chunk rows each
+    hold several whole rows (contiguous segments of slots, column-
+    disjoint within a chunk row).  One in-kernel gather, per-slot row
+    aggregates by a segmented reduction, candidates with per-slot sides,
+    and the one-hot scatter into the resident accumulators."""
+    _init_accumulators(bl_ref, bu_ref, inf)
+    val = val_ref[...]
+    col = col_ref[...].astype(jnp.int32)
+    lb_g, ub_g = _gather_bounds_tile(col, lb_ref, ub_ref, inf=inf, block=block)
+    aggs = tile_segment_aggregates(
+        val, lb_g, ub_g, seg_ref[...].astype(jnp.int32), inf
+    )
+    lcand, ucand = tile_candidates(
+        val, lb_g, ub_g, ii_ref[...].astype(jnp.int32) != 0, *aggs,
+        lhs_ref[...], rhs_ref[...], int_eps, inf,
+    )
+    _scatter_tile(lcand, ucand, col, bl_ref, bu_ref, inf=inf, block=block)
+
+
+def packed_round_tiles(
+    val,
+    col,
+    is_int_g,
+    seg,
+    lhs_s,
+    rhs_s,
+    lb,
+    ub,
+    n_pad: int,
+    int_eps: float,
+    inf: float = INF,
+    interpret: bool | None = None,
+    block: int = LANE,
+):
+    """Packed round: ``(T, R, K)`` packed tiles (``seg`` the slot's
+    segment within its chunk row, -1 on padding; ``lhs_s``/``rhs_s`` its
+    row's sides) + ``(n_pad,)`` bounds -> ``(n_pad,)`` best_l / best_u.
+    The rows of one chunk row must not share a column (the scatter's
+    one-hot placement then holds)."""
+    interpret = resolve_interpret(interpret, val.dtype)
+    _check_windows(n_pad, block)
+    t, r, k = val.shape
+    tile = _tile_spec(r, k)
+    vec = _vec_spec(n_pad, block)
+    out_shape = [jax.ShapeDtypeStruct((n_pad // block, block), val.dtype)] * 2
+    fn = pl.pallas_call(
+        functools.partial(_packed_round_kernel, int_eps=int_eps, inf=inf, block=block),
+        grid=(t,),
+        in_specs=[tile] * 6 + [vec, vec],
+        out_specs=[vec, vec],
+        out_shape=out_shape,
+        name=KERNEL_NAMES["packed_round"],
+        interpret=interpret,
+    )
+    best_l, best_u = fn(
+        val, col, _int_operand(is_int_g), _int_operand(seg), lhs_s, rhs_s,
         _lane_rows(lb, block), _lane_rows(ub, block),
     )
     return best_l.reshape(n_pad), best_u.reshape(n_pad)

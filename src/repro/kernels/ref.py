@@ -215,6 +215,31 @@ def candidates_scatter_tiles_ref(
     return scatter_round_ref(lcand, ucand, col, n_pad, inf)
 
 
+def packed_round_ref(
+    val, col, is_int_g, seg, lhs_s, rhs_s, lb, ub, n_pad: int,
+    int_eps: float, inf: float = INF,
+):
+    """Oracle for the packed kernel: ``(T, R, K)`` packed tiles, whose
+    chunk rows hold several rows as segments (``seg``, -1 on padding)
+    with per-slot sides, + ``(n_pad,)`` bounds -> ``(n_pad,)`` x2.  Each
+    slot is a row of width one whose aggregates are the segment sums of
+    its row, so the per-row oracles apply unchanged."""
+    t, r, k = val.shape
+    chunk = jnp.arange(t * r).reshape(t, r, 1)
+    gid = jnp.where(seg >= 0, chunk * k + seg, t * r * k).reshape(-1)
+    one = lambda x: x[..., None]
+    lb_g, ub_g = one(lb[col]), one(ub[col])
+    parts = activities_tiles_ref(one(val), lb_g, ub_g, inf)
+    tot = lambda x: jax.ops.segment_sum(
+        x.reshape(-1), gid, num_segments=t * r * k + 1
+    )[gid].reshape(t, r, k)
+    lcand, ucand = candidates_tiles_ref(
+        one(val), lb_g, ub_g, one(is_int_g != 0), *(tot(x) for x in parts),
+        lhs_s, rhs_s, int_eps, inf,
+    )
+    return scatter_round_ref(lcand[..., 0], ucand[..., 0], col, n_pad, inf)
+
+
 # ---------------------------------------------------------------------------
 # Batched oracles: flat super-tile stream, per-instance column windows
 # ---------------------------------------------------------------------------

@@ -106,6 +106,21 @@ def test_activities_gather_candidates_scatter_compile(one_chip):
              ((n_pad,), F32), ((n_pad,), F32))
 
 
+def test_packed_round_compiles(one_chip):
+    """The packed round at the narrow presolve cell's width (n = 2*10^4,
+    n_pad 20096), with the packed stream's int8 segment ids."""
+    n_pad = 20096
+
+    def fn(val, col, ii, seg, lhs, rhs, lb, ub):
+        return kern.packed_round_tiles(
+            val, col, ii, seg, lhs, rhs, lb, ub, n_pad, 1e-6, interpret=False
+        )
+
+    text = _compile(one_chip, fn, *_tiles(n_pad), ((T, R, K), jnp.int8),
+                    ((T, R, K), F32), ((T, R, K), F32), ((n_pad,), F32), ((n_pad,), F32))
+    assert _kernel_calls(text) == [kern.KERNEL_NAMES["packed_round"]]
+
+
 def test_apply_updates_compiles(one_chip):
     n_pad = ops.SCATTER_MAX_NPAD
 
