@@ -16,10 +16,10 @@ Engine anatomy (see README "fused-scatter dataflow"):
     reduction into ``(2, n_pad)`` accumulators that stay in VMEM across all
     grid steps; a small merge kernel then folds them into (lb, ub) in place
     (``input_output_aliases``).  NO nnz-shaped tensor -- neither gathered
-    bounds nor candidates -- is produced in HBM during a round.  Where it
-    halves the chunk rows, short rows are packed into shared chunk rows
-    first (:class:`PackedStream`): the kernel's gather and scatter cost
-    the same per chunk row however few of its slots are used.
+    bounds nor candidates -- is produced in HBM during a round.  Under
+    ``fused="auto"`` short rows are packed into shared chunk rows first
+    (:class:`PackedStream`): the kernel's gather and scatter cost the same
+    per chunk row however few of its slots are used.
   * ``scatter="partitioned"`` -- the column-slab engine for instances whose
     ``n_pad`` exceeds the VMEM accumulator budget: the padded column space
     is split into balanced slabs (``default_slab_width``, capped at
@@ -603,21 +603,17 @@ def _first_fit_rows(starts, lengths, cols, k, open_rows=PACK_OPEN_ROWS):
 def build_packed_stream(val, col, chunk_row, lhs1, rhs1, is_int, ii_dtype):
     """Pack a single instance's ``(T, R, K)`` block-ELL stream (host
     arrays; ``lhs1``/``rhs1`` the ``(m + 1,)`` padded sides) into a
-    :class:`PackedStream`, or ``None`` where packing does not pay: the
-    packed and long chunk rows together must be at most half of the
-    block-ELL stream's row-holding chunks (instances whose rows are mostly
-    long keep their tiles)."""
+    :class:`PackedStream`.  An instance with no short rows gets only the
+    long sub-stream, which is the block-ELL split pair over its chunks."""
     val = np.asarray(val)
     t, r, k = val.shape
     m = len(lhs1) - 1
     cval, ccol, crow, _, _ = chunk_stream(val, col, chunk_row)
     held = crow < m
-    if not held.any():
-        return None
     nz = cval != 0
     cnt = nz.sum(axis=1)
-    chunks = np.bincount(crow[held], minlength=m)
-    short = held & (chunks[np.minimum(crow, m - 1)] == 1) & (cnt > 0)
+    chunks = np.bincount(crow[held], minlength=m + 1)
+    short = held & (chunks[crow] == 1) & (cnt > 0)
     sc = np.flatnonzero(short)
     s_nz = nz[sc]
     s_len = cnt[sc]
@@ -628,8 +624,6 @@ def build_packed_stream(val, col, chunk_row, lhs1, rhs1, is_int, ii_dtype):
     long_ = held & (cnt > 0) & ~short
     long_[sc[~placed]] = True
     lc = np.flatnonzero(long_)
-    if used + lc.size > held.sum() / 2:
-        return None
 
     # Packed stream: every placed row's nonzeros at consecutive slots.
     tp = -(-used // r)
@@ -650,8 +644,7 @@ def build_packed_stream(val, col, chunk_row, lhs1, rhs1, is_int, ii_dtype):
         lay(0, val.dtype, cval[sc][s_nz][take]),
         lay(0, np.asarray(col).dtype, p_col),
         lay(0, ii_dtype, np.asarray(is_int)[p_col]),
-        lay(-1, np.int8 if np.dtype(ii_dtype) == np.int8 and k <= 128 else np.int32,
-            np.repeat(segs, p_len)),
+        lay(-1, np.int8 if k <= 128 else np.int32, np.repeat(segs, p_len)),
         lay(0, val.dtype, lhs1[rows]),
         lay(0, val.dtype, rhs1[rows]),
     )
@@ -806,7 +799,7 @@ class PreparedBlockEll:
     _slabs: dict = dataclasses.field(
         default_factory=dict, compare=False, repr=False
     )
-    # The packed stream (or None where it does not pay), built lazily.
+    # The packed stream of the fused engine, built lazily.
     _packed: dict = dataclasses.field(
         default_factory=dict, compare=False, repr=False
     )
@@ -840,11 +833,11 @@ class PreparedBlockEll:
             self._slabs[s] = part
         return part
 
-    def packed_stream(self) -> PackedStream | None:
+    def packed_stream(self) -> PackedStream:
         """This instance's short rows packed into shared chunk rows, for
-        the fused engine (:class:`PackedStream`), or ``None`` where packing
-        does not pay.  Built once from the resident tiles (a host-side pass)
-        and cached on the prep, like :meth:`slab_partition`."""
+        the fused engine (:class:`PackedStream`).  Built once from the
+        resident tiles (a host-side pass) and cached on the prep, like
+        :meth:`slab_partition`."""
         if "stream" not in self._packed:
             d = self.d
             self._packed["stream"] = build_packed_stream(
@@ -894,8 +887,7 @@ _prep_cache = LRU(maxsize=32, on_evict=_drop_runners_for)
 
 @PROGRAM.traced("prop.prepare")
 def prepare_block_ell(
-    p: Problem, tile_rows: int = 8, tile_width: int = 128, dtype=None,
-    *, pack: bool = False,
+    p: Problem, tile_rows: int = 8, tile_width: int = 128, dtype=None
 ) -> PreparedBlockEll:
     """One-time setup for kernel-backed propagation, LRU-cached per matrix
     STRUCTURE (``csr``/``lhs``/``rhs``/``is_int`` identity -- maxsize 32,
@@ -909,19 +901,10 @@ def prepare_block_ell(
     live; a hit from a problem whose bounds differ from the cached defaults
     returns a cheap bounds-swapped view sharing every device tile.
     ``dtype=None`` is the instance's own dtype, float32 on a TPU
-    (:func:`~repro.core.types.resolve_dtype`).  ``pack=True`` also builds
-    (once per prep) the packed stream of the fused engine
-    (:meth:`PreparedBlockEll.packed_stream`).  Each call is one
+    (:func:`~repro.core.types.resolve_dtype`).  Each call is one
     ``prop.prepare`` span of the program tracer, ``hit`` telling a cache
-    hit from a build and ``packed`` whether the prep carries a packed
-    stream for this call.
+    hit from a build.
     """
-    prep = _prepare_cached(p, tile_rows, tile_width, dtype)
-    PROGRAM.note(packed=pack and prep.packed_stream() is not None)
-    return prep
-
-
-def _prepare_cached(p: Problem, tile_rows: int, tile_width: int, dtype) -> PreparedBlockEll:
     dt = resolve_dtype(dtype, p.csr.val.dtype)
     anchors = _structure_anchors(p)
     key = tuple(id(a) for a in anchors) + (tile_rows, tile_width, dt.str)
@@ -1339,16 +1322,19 @@ def round_fn_for(
     overrides the partitioned engine's column-slab width (default
     :data:`SLAB_NPAD`; ignored by the other scatter modes).  ``fused=None``
     lets the instance choose: with Pallas and the fused engine, the packed
-    stream where it pays (:meth:`PreparedBlockEll.packed_stream`), else
-    the single kernel when every row fits one chunk."""
+    stream (:meth:`PreparedBlockEll.packed_stream`), else the single
+    kernel when every row fits one chunk."""
     scatter = _resolve_scatter(scatter, prep)
     do_fuse = prep.fits_one_chunk if fused is None else bool(fused)
     packed = _packed_for(prep, use_pallas, scatter, fused is None)
-    eps = cfg.eps_for(prep.d.val.dtype)
+    return _round_fn(prep, cfg, use_pallas, scatter, do_fuse, interpret, slab, packed)
+
+
+def _round_fn(prep, cfg, use_pallas, scatter, do_fuse, interpret, slab, packed):
     return functools.partial(
         _prepared_round,
         prep,
-        eps=eps,
+        eps=cfg.eps_for(prep.d.val.dtype),
         int_eps=cfg.int_eps,
         inf=cfg.inf,
         use_pallas=use_pallas,
@@ -1361,14 +1347,10 @@ def round_fn_for(
     )
 
 
-def _packs(use_pallas: bool, scatter: str, auto: bool) -> bool:
-    """The packed stream serves the Pallas fused engine when the caller
-    leaves the tile layout to the instance (``fused`` auto)."""
-    return use_pallas and scatter == "fused" and auto
-
-
 def _packed_for(prep: PreparedBlockEll, use_pallas, scatter, auto):
-    return prep.packed_stream() if _packs(use_pallas, scatter, auto) else None
+    """The packed stream serves the Pallas fused engine whenever the
+    caller leaves the tile layout to the instance (``fused`` auto)."""
+    return prep.packed_stream() if use_pallas and scatter == "fused" and auto else None
 
 
 # ---------------------------------------------------------------------------
@@ -1467,9 +1449,9 @@ def propagate_block_ell(
     """Kernel-backed propagation.
 
     ``fused='auto'`` lets the instance pick its tiles: with Pallas and the
-    fused engine, the packed stream wherever it halves the chunk rows
-    (short rows share chunk rows, :class:`PackedStream`), else the Alg.-3
-    fusion whenever every row fits in one chunk (the paper's common case).
+    fused engine, the packed stream (short rows share chunk rows,
+    :class:`PackedStream`), else the Alg.-3 fusion whenever every row fits
+    in one chunk (the paper's common case).
     ``scatter='auto'`` picks the fully fused in-VMEM column reduction while the padded column count fits the
     accumulator budget and the column-slab ``partitioned`` engine beyond it
     (``slab`` overrides its window width); ``scatter='segment'`` forces the
@@ -1498,9 +1480,10 @@ def propagate_block_ell(
     cache key (on/off are distinct compiled runners, each cached once).
 
     Each call is one ``prop.presolve`` span of the program tracer
-    (``obs.trace.PROGRAM``, attrs ``engine``, ``n_pad``, and ``slots`` and
-    ``nnz``: the slots of the tile streams a round runs and the nonzeros
-    they hold), from entry to return: the launch is asynchronous and nothing waits for the device.
+    (``obs.trace.PROGRAM``, attrs ``engine``, ``n_pad``, ``packed`` (the
+    round runs the packed stream), and ``slots`` and ``nnz``: the slots of
+    the tile streams a round runs and the nonzeros they hold), from entry
+    to return: the launch is asynchronous and nothing waits for the device.
     Inside it are the ``prop.prepare``, ``prop.upload`` and ``prop.launch``
     spans (``built`` on a runner-cache miss); a two-tier call nests one
     ``prop.presolve`` span per tier."""
@@ -1557,13 +1540,12 @@ def propagate_block_ell(
         patience = policy.patience
     scatter = _scatter_for(scatter, kern.col_pad(p.n))
     auto = fused == "auto"
-    pack = _packs(use_pallas, scatter, auto)
-    prep = prepare_block_ell(p, tile_rows, tile_width, dtype, pack=pack)
+    prep = prepare_block_ell(p, tile_rows, tile_width, dtype)
     do_fuse = prep.fits_one_chunk if auto else bool(fused == "yes" or fused is True)
     packed = _packed_for(prep, use_pallas, scatter, auto)
     PROGRAM.note(
-        engine=scatter, n_pad=prep.n_pad, slots=_round_slots(prep, scatter, packed, slab),
-        nnz=int(p.nnz),
+        engine=scatter, n_pad=prep.n_pad, packed=packed is not None,
+        slots=_round_slots(prep, scatter, packed, slab), nnz=int(p.nnz),
     )
     do_donate = donate_supported() if donate is None else bool(donate)
     n = prep.n
@@ -1576,8 +1558,8 @@ def propagate_block_ell(
 
     def build():
         donate_kw = {"donate_argnums": (0, 1)} if do_donate else {}
-        round_fn = round_fn_for(
-            prep, cfg, use_pallas, scatter, None if auto else do_fuse, interpret, slab
+        round_fn = _round_fn(
+            prep, cfg, use_pallas, scatter, do_fuse, interpret, slab, packed
         )
         if driver == "host_loop":
             # Progress is computed INSIDE the jit, where the pre-round

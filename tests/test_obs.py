@@ -363,18 +363,20 @@ def test_presolve_spans_nest_under_the_call():
     assert len(calls) == 2 and all(c.parent_id is None for c in calls)
     slots = prepare_block_ell(p).d.val.size  # the jnp round runs the tiles
     for c in calls:
-        assert c.attrs == {"engine": "fused", "n_pad": 128, "slots": slots, "nnz": p.nnz}
+        assert c.attrs == {
+            "engine": "fused", "n_pad": 128, "packed": False, "slots": slots, "nnz": p.nnz,
+        }
     first, second = (_children(spans, c) for c in calls)
     # The first call prepares (tiles up) and builds its runner; the
     # second hits both caches and uploads only its two bound vectors.
     assert set(first) == {"prop.prepare", "prop.launch"}
-    assert first["prop.prepare"].attrs == {"hit": False, "packed": False}
+    assert first["prop.prepare"].attrs == {"hit": False}
     assert first["prop.launch"].attrs == {"built": True}
     up = [s for s in spans if s.parent_id == first["prop.prepare"].span_id]
     assert [s.name for s in up] == ["prop.upload"]
     assert up[0].attrs["site"] == "prepare" and up[0].attrs["bytes"] > 0
     assert set(second) == {"prop.prepare", "prop.upload", "prop.launch"}
-    assert second["prop.prepare"].attrs == {"hit": True, "packed": False}
+    assert second["prop.prepare"].attrs == {"hit": True}
     assert second["prop.launch"].attrs == {"built": False}
     assert second["prop.upload"].attrs == {"site": "bounds", "bytes": 2 * p.n * 8}
     for c in calls:

@@ -4,7 +4,7 @@ Three layers, on the CPU in interpret mode:
   * packing invariants: every nonzero lands once, the rows of a packed
     chunk row are contiguous segments that share no column, rows longer
     than ``K`` (or repeating a column) keep their own chunks in the long
-    sub-stream, and the half rule takes both branches;
+    sub-stream, and ``fused="auto"`` packs whatever the rows' lengths;
   * the ``prop_packed_round`` kernel against ``ref.packed_round_ref``;
   * the engine against the float64 sequential oracle, in float64 and
     within the float32 band, on random ``mixed`` instances and on edge
@@ -62,7 +62,7 @@ def _problem(rows, n, seed=0, all_inf=False):
 
 def _edge_rows(n=40):
     """Length 1, exactly K, K + 1, empty rows, a row that repeats a column,
-    rows that share columns, then short rows enough for packing to pay."""
+    rows that share columns, then short random rows."""
     rng = np.random.default_rng(3)
     rows = [[5], list(range(K)), list(range(3, 3 + K + 1)), [], [7, 7, 9], []]
     rows += [list(range(20, 25))] * 6  # share every column
@@ -87,7 +87,6 @@ def _stream(p, k=K):
 @pytest.mark.parametrize("name,p", _packing_instances())
 def test_every_nonzero_lands_once(name, p):
     s = _stream(p)
-    assert s is not None, name
     row = np.repeat(np.arange(p.m), np.diff(p.csr.row_ptr))
     want = collections.Counter(zip(p.csr.col.tolist(), p.csr.val.tolist(),
                                    p.lhs[row].tolist(), p.rhs[row].tolist()))
@@ -137,14 +136,28 @@ def test_long_and_repeating_rows_keep_their_chunks():
     assert (np.asarray(s.val) != 0).sum() == sum(len(r) for r in rows) - (K + 1) - 3
 
 
-def test_half_rule_takes_both_branches():
-    short = _problem([[i % 30, (i + 1) % 30] for i in range(64)], 30)
-    assert _stream(short) is not None
-    long_ = _problem([list(range(i, i + K + 2)) for i in range(12)], 40)
-    assert _stream(long_) is None
-    # A full chunk row per row gains nothing either.
-    full = _problem([list(range(i, i + K)) for i in range(12)], 40)
-    assert _stream(full) is None
+def _shape_cases():
+    """Short rows (packed many to a chunk row), rows of exactly K (one to a
+    chunk row) and rows longer than K (the long sub-stream alone)."""
+    return [
+        ("short", _problem([[i % 30, (i + 1) % 30] for i in range(64)], 30), 2 * 64, 0),
+        ("full", _problem([list(range(i, i + K)) for i in range(12)], 40), 12 * K, 0),
+        ("long", _problem([list(range(i, i + K + 2)) for i in range(12)], 40), 0, 12),
+    ]
+
+
+@pytest.mark.parametrize("name,p,packed_nnz,n_long", _shape_cases())
+def test_auto_packs_whatever_the_row_lengths(name, p, packed_nnz, n_long):
+    s = _stream(p)
+    assert (np.asarray(s.val) != 0).sum() == packed_nnz, name
+    assert s.n_long == n_long and s.long_chunks == 2 * n_long, name
+    a = propagate_sequential(p)
+    b = propagate_block_ell(p, tile_rows=4, tile_width=K)
+    call = [x for x in PROGRAM.spans() if x.name == "prop.presolve"][-1]
+    assert call.attrs["packed"] is True
+    assert bool(a.infeasible) == bool(b.infeasible), name
+    if not bool(a.infeasible):
+        assert bounds_equal(a.lb, a.ub, b.lb, b.ub), name
 
 
 def test_packed_stream_is_never_built_off_the_packed_path():
@@ -158,7 +171,7 @@ def test_packed_stream_is_never_built_off_the_packed_path():
     propagate_nodes(p, lb, ub)  # the node engine keeps the block-ELL tiles
     assert not prepare_block_ell(p)._packed
     propagate_block_ell(p, tile_rows=4, tile_width=K)
-    assert prepare_block_ell(p, 4, K)._packed["stream"] is not None
+    assert "stream" in prepare_block_ell(p, 4, K)._packed
 
 
 def test_presolve_span_counts_slots_and_nnz():
@@ -167,14 +180,13 @@ def test_presolve_span_counts_slots_and_nnz():
     propagate_block_ell(p, tile_rows=4, tile_width=K)
     spans = PROGRAM.spans()
     call = [s for s in spans if s.name == "prop.presolve"][-1]
-    prep_span = [s for s in spans if s.name == "prop.prepare"
-                 and s.parent_id == call.span_id][-1]
     s = _stream(p)
     assert call.attrs["slots"] == s.slots and call.attrs["nnz"] == p.nnz
-    assert prep_span.attrs["packed"] is True
+    assert call.attrs["packed"] is True
     propagate_block_ell(p, tile_rows=4, tile_width=K, fused="no")
     call = [s for s in PROGRAM.spans() if s.name == "prop.presolve"][-1]
     assert call.attrs["slots"] == prepare_block_ell(p, 4, K).d.val.size
+    assert call.attrs["packed"] is False
 
 
 # ---------------------------------------------------------------------------
@@ -233,7 +245,6 @@ def _engine_cases():
 
 @pytest.mark.parametrize("name,p", _engine_cases())
 def test_packed_engine_matches_oracle(name, p):
-    assert _stream(p) is not None, name
     a = propagate_sequential(p)
     b = propagate_block_ell(p, tile_rows=4, tile_width=K)
     assert bool(a.infeasible) == bool(b.infeasible), name
