@@ -72,19 +72,6 @@ def test_host_call_presolve_is_the_median_of_the_window_calls(ring):
     assert read(ctx(presolve_rounds=[])) is None
 
 
-def test_slot_fill_presolve_reads_the_window_calls(ring):
-    for i, (slots, nnz) in enumerate([(1024, 10), (2048, 20), (4096, 1000), (8192, 3000)]):
-        sid = call(ring, "prop.presolve", float(i), 0.1, engine="fused", slots=slots, nnz=nnz)
-        call(ring, "prop.prepare", float(i), 0.01, parent_id=sid, hit=True, packed=True)
-    read = metric("slot_fill.presolve")
-    assert read(ctx(presolve_rounds=[8, 8])) == pytest.approx(100.0 * 4000 / 12288)
-    assert read(ctx(presolve_rounds=[8] * 5)) is None  # the ring holds four calls
-    assert read(ctx(presolve_rounds=[])) is None
-    # A program whose spans carry no slot count (an older program) reads None.
-    call(ring, "prop.presolve", 5.0, 0.1, engine="fused", n_pad=128)
-    assert read(ctx(presolve_rounds=[8])) is None
-
-
 def test_node_metrics_read_the_window_frontiers(ring):
     for i in range(4):  # two warm frontiers, then a window of two
         sid = call(ring, "prop.nodes", float(i), 0.002 * (i + 1), nodes=64, n_pad=10112)
@@ -109,7 +96,7 @@ def test_node_metrics_need_whole_window_frontiers(ring, name):
 
 @pytest.mark.parametrize("name", ["round_kernel_ms.presolve", "outside_kernels_ms.presolve",
                                   "host_call_ms.presolve", "host_call_ms.nodes",
-                                  "h2d_bytes.nodes", "slot_fill.presolve"])
+                                  "h2d_bytes.nodes"])
 def test_a_program_without_the_instrumentation_reads_none(monkeypatch, name):
     monkeypatch.delattr(prop_round, "KERNEL_NAMES")
     monkeypatch.delattr(obs_trace, "PROGRAM")
